@@ -1672,6 +1672,8 @@ def main():
                     help="also write the result rows to PATH as JSON "
                          "(the BENCH_*.json CI artifacts)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.swap:
         if args.prefix or args.spec_decode or args.open_loop \
                 or args.chaos or args.window or args.dp > 1 \

@@ -96,7 +96,6 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         _flash_kernel, scale=sc, causal=causal, window=window,
         bq=bq, bk=bk, n_k=n_k, seq_q=Sq, seq_k=Sk)
 
-    from repro.kernels.ops import _compiler_params  # lazy: avoid import cycle
     out = pl.pallas_call(
         kernel,
         grid=(B, H, Sq // bq, n_k),
@@ -108,7 +107,7 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((bq, 1), jnp.float32),         # running denom
             pltpu.VMEM((bq, D), jnp.float32),         # output accumulator
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="flash_attention_fwd",

@@ -12,20 +12,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
-
-
-def _compiler_params(*, dimension_semantics):
-    """Version-portable ``pltpu`` compiler params.
-
-    The class was renamed ``TPUCompilerParams`` -> ``CompilerParams`` across
-    JAX releases; the installed JAX may have either.  Every kernel in this
-    package goes through this one helper so the compat shim lives in exactly
-    one place.
-    """
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(dimension_semantics=dimension_semantics)
-
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
@@ -153,7 +139,6 @@ def paged_attention_sharded(mesh, q, k_pages, v_pages, block_tables,
     fallback-to-replicated policy before pools ever get here).
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.parallel.compress import shard_map_compat
     # q/output heads sharded; a 4-D q is the K-token decode window
     # (B, K, H, D) — same head axis, one extra replicated window dim
     qs = (P(None, None, axis, None) if q.ndim == 4
@@ -166,13 +151,16 @@ def paged_attention_sharded(mesh, q, k_pages, v_pages, block_tables,
             return paged_attention(lq, kp, vp, bt, ln, window=window,
                                    ring=ring, scale=scale, k_scale=ks,
                                    v_scale=vs, impl=impl)
-        f = shard_map_compat(local, mesh, (qs, ps, ps, ss, ss, bs, ls), qs)
+        f = jax.shard_map(local, mesh=mesh,
+                          in_specs=(qs, ps, ps, ss, ss, bs, ls),
+                          out_specs=qs, check_vma=False)
         o = f(q, k_pages, v_pages, k_scale, v_scale, block_tables, lengths)
     else:
         def local(lq, kp, vp, bt, ln):
             return paged_attention(lq, kp, vp, bt, ln, window=window,
                                    ring=ring, scale=scale, impl=impl)
-        f = shard_map_compat(local, mesh, (qs, ps, ps, bs, ls), qs)
+        f = jax.shard_map(local, mesh=mesh, in_specs=(qs, ps, ps, bs, ls),
+                          out_specs=qs, check_vma=False)
         o = f(q, k_pages, v_pages, block_tables, lengths)
     if not gather_output:
         return o                                  # head-sharded, per qs

@@ -341,12 +341,11 @@ def paged_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
         out_specs=q_spec,
         scratch_shapes=scratch,
     )
-    from repro.kernels.ops import _compiler_params  # lazy: avoid import cycle
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name=(f"paged_attention_decode_{quant}"

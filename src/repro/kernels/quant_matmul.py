@@ -90,7 +90,6 @@ def quant_matmul_pallas(x: jnp.ndarray, wq: jnp.ndarray, scale: jnp.ndarray,
 
     kernel = functools.partial(_qmm_kernel, bits=bits, group=group,
                                bk=bk, bn=bn, n_k=n_k, out_dtype=out_dtype)
-    from repro.kernels.ops import _compiler_params  # lazy: avoid import cycle
     return pl.pallas_call(
         kernel,
         grid=(M // bm, N // bn, n_k),
@@ -98,7 +97,7 @@ def quant_matmul_pallas(x: jnp.ndarray, wq: jnp.ndarray, scale: jnp.ndarray,
         out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=f"quant_matmul_w{bits}",

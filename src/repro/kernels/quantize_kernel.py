@@ -31,7 +31,6 @@ def quantize_rowwise_pallas(x: jnp.ndarray, *, bits: int = 8, bm: int = 128,
     assert M % bm == 0, (M, bm)
     qmax = (1 << (bits - 1)) - 1
     kernel = functools.partial(_quantize_kernel, qmax=qmax)
-    from repro.kernels.ops import _compiler_params  # lazy: avoid import cycle
     return pl.pallas_call(
         kernel,
         grid=(M // bm,),
@@ -40,7 +39,7 @@ def quantize_rowwise_pallas(x: jnp.ndarray, *, bits: int = 8, bm: int = 128,
                    pl.BlockSpec((bm, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((M, K), jnp.int8),
                    jax.ShapeDtypeStruct((M, 1), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name=f"quantize_rowwise_int{bits}",

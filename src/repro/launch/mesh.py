@@ -10,21 +10,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-
-
-def make_mesh_compat(shape, axes, devices=None):
-    """``jax.make_mesh`` with explicit Auto axis types where the installed
-    JAX supports them (the AxisType enum + ``axis_types=`` kwarg landed
-    together; older releases have neither and default to Auto anyway).
-    All mesh construction — production, debug, and tests — goes through
-    this one guard."""
-    devices = devices if devices is not None else jax.devices()
-    n = int(np.prod(shape))
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, devices=devices[:n],
-                             axis_types=(axis_type.Auto,) * len(shape))
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -36,9 +22,12 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             "run under launch/dryrun.py which forces 512 host devices")
-    return make_mesh_compat(shape, axes, devices)
+    return jax.make_mesh(shape, axes, devices=devices[:n],
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_debug_mesh(dp: int = 2, tp: int = 2):
     """Small mesh for multi-device unit tests (subprocess with 4/8 devs)."""
-    return make_mesh_compat((dp, tp), ("data", "model"))
+    return jax.make_mesh((dp, tp), ("data", "model"),
+                         devices=jax.devices()[:dp * tp],
+                         axis_types=(AxisType.Auto,) * 2)

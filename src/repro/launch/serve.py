@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serve.engine import ServeConfig, generate, load_quantized
 
@@ -67,6 +68,7 @@ def _run_paged(args, spec, params):
     """Continuous batching end-to-end: submit ``--batch`` requests with
     the prompt spread, drain the scheduler, report stats."""
     from repro.serve.backend import make_backend
+    from repro.serve.paged_cache import device_kv_budget
     from repro.serve.scheduler import (ContinuousBatchingEngine, Request,
                                       SchedulerConfig)
     rng = np.random.default_rng(1)
@@ -76,10 +78,13 @@ def _run_paged(args, spec, params):
                                 args.prompt_len + 1))
         prompt = rng.integers(0, spec.vocab_size, size=plen).astype(np.int32)
         reqs.append(Request(i, prompt, args.steps))
+    # the pool takes what the device holds next to the weights; a
+    # device that reports no memory limit (the CPU) gets 64 MB
+    budget = device_kv_budget(params, jax.devices()[0]) or 64e6
     cfg = SchedulerConfig(
         max_slots=min(8, args.batch), page_size=16,
         max_seq=args.prompt_len + args.steps + 16,
-        kv_budget_bytes=64e6, cache_dtype=args.cache_dtype,
+        kv_budget_bytes=budget, cache_dtype=args.cache_dtype,
         spec_k=args.spec_k,
         prefill_chunk_tokens=args.prefill_chunk)
     if args.dp > 1:
@@ -190,6 +195,7 @@ def main():
                          "tables: per-slot KV bounded at O(window) "
                          "pages, out-of-window pages recycled in place")
     args = ap.parse_args()
+    enable_compile_cache()
 
     spec = ARCHS[args.arch]
     if args.local:

@@ -18,26 +18,6 @@ import jax
 import jax.numpy as jnp
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` with replication checking off.
-
-    ``jax.shard_map(..., check_vma=)`` replaced
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)`` across JAX
-    releases; callers of ``compressed_allreduce`` go through this shim.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:   # jax.shard_map exists but predates the rename
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 def quantize_grad(g: jnp.ndarray, qmax: int = 127):
     amax = jnp.max(jnp.abs(g))
     scale = jnp.maximum(amax, 1e-12) / qmax
@@ -49,10 +29,7 @@ def compressed_allreduce(grads: Any, residual: Any, axis_name,
                          mean: bool = True) -> Tuple[Any, Any]:
     """Inside shard_map: all-reduce grads over ``axis_name`` in int8 with
     error feedback. Returns (synced_grads_f32, new_residual)."""
-    if hasattr(jax.lax, "axis_size"):
-        n = jax.lax.axis_size(axis_name)
-    else:                          # older JAX: psum of 1 over the axis
-        n = jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def one(g, r):
         gf = g.astype(jnp.float32) + r

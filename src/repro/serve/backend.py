@@ -54,6 +54,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, SingleDeviceSharding
 
 from repro.core.model_config import ModelSpec
 from repro.models import lm
@@ -318,8 +319,12 @@ class SingleDeviceBackend(PagedKVBackend):
     #: shardings (the sharded backend with dividable head counts).
     weights_sharded = False
 
-    def __init__(self, params: Any, spec: ModelSpec, cfg):
+    def __init__(self, params: Any, spec: ModelSpec, cfg, device=None):
+        # ``device``: the one device this backend's params and pool are
+        # committed to (a dp replica's own chip); None leaves them on
+        # the default device
         self.params, self.spec, self.cfg = params, spec, cfg
+        self.device = device
         # Uniformly sliding-window stacks get a RING block table bounded
         # at O(window) pages per slot (unless cfg.windowed_kv forces the
         # mask-only reference); everything else keeps the flat layout.
@@ -349,13 +354,23 @@ class SingleDeviceBackend(PagedKVBackend):
                                                 ring=self.ring)
 
     def _init_cache(self):
-        """Build the paged device cache; subclasses override to create
-        it already laid out across their devices."""
-        return lm.init_cache(self.spec, self.cfg.max_slots, self.cfg.max_seq,
-                             self.cfg.cache_dtype, paged=self.layout)
+        """Build the paged device cache — written straight onto
+        ``self.device`` when one was given, so a replica's pool never
+        passes through the default device; subclasses override to
+        create it already laid out across their devices."""
+        build = functools.partial(lm.init_cache, self.spec,
+                                  self.cfg.max_slots, self.cfg.max_seq,
+                                  self.cfg.cache_dtype, paged=self.layout)
+        if self.device is None:
+            return build()
+        return jax.jit(build, out_shardings=SingleDeviceSharding(
+            self.device))()
 
     def _place(self) -> None:
-        """Hook for subclasses to device_put the params (shardings)."""
+        """Commit the params to ``self.device`` (subclasses place them
+        with their shardings instead)."""
+        if self.device is not None:
+            self.params = jax.device_put(self.params, self.device)
 
     def param_bytes_per_device(self) -> int:
         """Bytes of weight state ONE device holds (= per-device weight
@@ -471,7 +486,6 @@ class ShardedPagedBackend(SingleDeviceBackend):
     def __init__(self, params: Any, spec: ModelSpec, cfg,
                  tp: Optional[int] = None,
                  devices: Optional[List] = None):
-        from repro.launch.mesh import make_mesh_compat
         from repro.parallel.sharding import ShardingRules
         devices = devices if devices is not None else jax.devices()
         tp = tp if tp is not None else len(devices)
@@ -483,8 +497,9 @@ class ShardedPagedBackend(SingleDeviceBackend):
                 f"tp={tp} needs {tp} devices, have {len(devices)} — on CPU "
                 "run under XLA_FLAGS=--xla_force_host_platform_device_count=N")
         self.tp = tp
-        self._mesh = make_mesh_compat((1, tp), ("data", "model"),
-                                      devices=devices)
+        self._mesh = jax.make_mesh((1, tp), ("data", "model"),
+                                   devices=devices[:tp],
+                                   axis_types=(AxisType.Auto,) * 2)
         self.rules = ShardingRules(self._mesh, spec)
         super().__init__(params, spec, cfg)
 
@@ -538,8 +553,11 @@ def make_backend(params: Any, spec: ModelSpec, cfg, *,
     the single-device pool, > 1 the tensor-parallel backend (KV pools
     AND weights sharded) over the first ``devices`` jax devices —
     or over an explicit ``device_list`` (the dp router hands each
-    replica its own disjoint slice)."""
+    replica its own disjoint slice; a one-device slice commits the
+    single-device backend to that device)."""
     if devices <= 1:
-        return SingleDeviceBackend(params, spec, cfg)
+        return SingleDeviceBackend(
+            params, spec, cfg,
+            device=device_list[0] if device_list else None)
     return ShardedPagedBackend(params, spec, cfg, tp=devices,
                                devices=device_list)

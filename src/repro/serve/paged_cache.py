@@ -46,7 +46,7 @@ import functools
 import hashlib
 from collections import OrderedDict
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -499,6 +499,25 @@ def make_layout(spec: ModelSpec, *, max_seq: int, page_size: int = 16,
         num_pages = min(num_pages, max_slots * pps + 1)
     return lm.PagedLayout(num_pages=num_pages, page_size=page_size,
                           pages_per_slot=pps)
+
+
+# Device bytes held back from the KV pool for prefill activations: a
+# 1024-token prompt at 1B-model widths.
+ACTIVATION_HEADROOM_BYTES = 3 << 30
+
+
+def device_kv_budget(params: Any, device) -> Optional[float]:
+    """Per-device KV byte budget from the memory ``device`` reports: its
+    limit less the bytes of ``params`` and ``ACTIVATION_HEADROOM_BYTES``
+    (``kv_budget``, as on the ``device_bytes`` path of ``make_layout``).
+    None where the device reports no limit (the CPU backend)."""
+    stats = device.memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    weights = sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(params))
+    return kv_budget(stats["bytes_limit"],
+                     MemoryBreakdown(weights=weights,
+                                     activations=ACTIVATION_HEADROOM_BYTES))
 
 
 def plan_for_layout(spec: ModelSpec, layout: lm.PagedLayout,

@@ -505,25 +505,22 @@ class PrefixRouter:
 
 def make_replicas(params, spec, cfg, *, dp: int, tp: int = 1) -> List:
     """dp independent engines over disjoint device slices: replica r
-    runs on ``jax.devices()[r*tp:(r+1)*tp]`` (tp=1 replicas share the
-    default device on a test host — independent on real hardware)."""
+    runs on ``jax.devices()[r*tp:(r+1)*tp]`` — a tp=1 replica has its
+    params and pool committed to its own device."""
     import jax
 
     from repro.serve.backend import make_backend
     from repro.serve.scheduler import ContinuousBatchingEngine
 
-    if tp > 1 and dp * tp > len(jax.devices()):
+    devices = jax.devices()
+    if dp * tp > len(devices):
         raise RuntimeError(
             f"dp={dp} x tp={tp} needs {dp * tp} devices, "
-            f"have {len(jax.devices())}")
+            f"have {len(devices)}")
     engines = []
     for r in range(dp):
-        if tp > 1:
-            devs = jax.devices()[r * tp:(r + 1) * tp]
-            backend = make_backend(params, spec, cfg, devices=tp,
-                                   device_list=devs)
-        else:
-            backend = make_backend(params, spec, cfg, devices=1)
+        backend = make_backend(params, spec, cfg, devices=tp,
+                               device_list=devices[r * tp:(r + 1) * tp])
         engines.append(ContinuousBatchingEngine(params, spec, cfg,
                                                 backend=backend))
     return engines

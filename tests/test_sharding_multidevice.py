@@ -34,8 +34,9 @@ from repro.models import lm
 from repro.parallel.sharding import ShardingRules
 from repro.train.optimizer import AdamWState, adamw_init
 from repro.train.train_step import TrainConfig, make_train_step
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 def sds(tree, sh):
     return jax.tree_util.tree_map(
         lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
@@ -136,8 +137,9 @@ def test_pod_axis_composes_with_data():
     out = _run("""
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(AxisType.Auto,) * 3)
 x = jax.ShapeDtypeStruct((8, 16), jnp.float32,
                          sharding=NamedSharding(mesh, P(("pod", "data"), None)))
 w = jax.ShapeDtypeStruct((16, 16), jnp.float32,

@@ -212,8 +212,11 @@ def test_decode_window_matches_sequential_steps(cache_dtype):
 
 def _reqs(seed=0, n=5, vocab=128, new_lo=8, new_hi=16):
     rng = np.random.default_rng(seed)
-    t1 = rng.integers(0, vocab, size=20).astype(np.int32)
-    t2 = rng.integers(0, vocab, size=25).astype(np.int32)
+    # periodic templates (a 4- and a 5-token motif tiled to 20 and 25
+    # tokens, like the benchmark's repetitive spec workload) so the
+    # n-gram drafter has repeats to draft from and verify windows run
+    t1 = np.tile(rng.integers(0, vocab, size=4), 5).astype(np.int32)
+    t2 = np.tile(rng.integers(0, vocab, size=5), 5).astype(np.int32)
     reqs = []
     for i in range(n):
         t = (t1, t2)[i % 2]

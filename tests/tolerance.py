@@ -8,7 +8,9 @@ stream diverges.  Elementwise comparison is therefore the wrong shape
 for banded token parity — the right invariant is that the streams agree
 on a long PREFIX (an early flip means a real bug, a late flip means a
 near-tie), which ``assert_close_tokens`` checks.  Logit-space checks
-stay elementwise with float tolerances (``assert_close_logits``).
+stay elementwise with float tolerances (``assert_close_logits``).  The
+measures themselves live in ``repro.serve.parity``, where the chip
+smoke run uses them too.
 
 Kept importable by name (tests/ is put on the subprocess PYTHONPATH by
 the multi-device tests) so every banded assertion shares one policy
@@ -16,19 +18,7 @@ instead of per-test ad-hoc ``np.testing`` calls.
 """
 import numpy as np
 
-
-def token_match_fraction(a, b) -> float:
-    """Fraction of the longer stream covered by the common prefix on
-    which ``a`` and ``b`` agree exactly (1.0 = identical streams)."""
-    a = np.asarray(a).ravel()
-    b = np.asarray(b).ravel()
-    n = max(len(a), len(b))
-    if n == 0:
-        return 1.0
-    m = min(len(a), len(b))
-    neq = np.nonzero(a[:m] != b[:m])[0]
-    prefix = int(neq[0]) if len(neq) else m
-    return prefix / n
+from repro.serve.parity import token_match_fraction
 
 
 def assert_close_tokens(a, b, *, min_match_frac: float = 0.9,
