@@ -38,6 +38,7 @@ from repro.configs import ARCHS
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serve.engine import ServeConfig, generate, load_quantized
+from repro.serve.spans import host_split_ms
 
 
 def _run_static(args, spec, params):
@@ -123,7 +124,16 @@ def _run_paged(args, spec, params):
               f"accepted ({acc:.2f}), "
               f"{st['decode_tokens'] / max(1, st['iterations']):.2f} "
               "tokens/iteration")
+    _print_host_split(eng.stats)
     print(np.stack([c.tokens[:8] for c in done[:4]]))
+
+
+def _print_host_split(stats):
+    """The host time of a step as the engine's spans split it."""
+    ms = host_split_ms(stats)
+    print(f"[serve] host per step: engine {ms['engine_self_ms']:.3f} ms, "
+          f"page table {ms['page_table_ms']:.3f} ms; decode dispatch "
+          f"{ms['decode_dispatch_ms']:.3f} ms per call")
 
 
 def _run_routed(args, spec, params, cfg, reqs):
@@ -147,6 +157,7 @@ def _run_routed(args, spec, params, cfg, reqs):
           f"spilled {int(agg['spilled'])}, "
           f"rebalanced {int(agg['rebalanced'])}, "
           f"prefix hits {int(agg['prefix_hit_tokens'])} tok")
+    _print_host_split(agg)
     print(np.stack([c.tokens[:8] for c in done[:4]]))
 
 

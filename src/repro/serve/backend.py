@@ -59,6 +59,7 @@ from jax.sharding import AxisType, SingleDeviceSharding
 from repro.core.model_config import ModelSpec
 from repro.models import lm
 from repro.serve import paged_cache as pc
+from repro.serve.spans import NO_SPANS
 
 
 # Module-level jits (spec/impl/mesh static): every engine instance — and
@@ -223,6 +224,13 @@ class PagedKVBackend:
     plan: Any                      # analytical PagedCachePlan
     cache: Any                     # device pytree (pools + block tables)
     tp: int = 1                    # tensor-parallel degree (1 = single)
+    _spans = NO_SPANS              # the owning engine's span recorder
+
+    def bind_spans(self, recorder) -> None:
+        """Record this backend's device calls as spans of ``recorder``
+        (the owning engine's ``spans.SpanRecorder``); until an engine
+        binds one, they record nothing."""
+        self._spans = recorder
 
     def admit_full(self, padded_tokens: np.ndarray, slot: int,
                    true_len: int, bt_row: np.ndarray) -> int:
@@ -390,18 +398,22 @@ class SingleDeviceBackend(PagedKVBackend):
         return total
 
     def admit_full(self, padded_tokens, slot, true_len, bt_row) -> int:
-        tok0, self.cache = self._admit(
-            self.params, {"tokens": jnp.asarray(padded_tokens)}, self.cache,
-            jnp.int32(slot), jnp.int32(true_len), jnp.asarray(bt_row))
-        return int(tok0)
+        with self._spans.span("backend.prefill"):
+            tok0, self.cache = self._admit(
+                self.params, {"tokens": jnp.asarray(padded_tokens)},
+                self.cache, jnp.int32(slot), jnp.int32(true_len),
+                jnp.asarray(bt_row))
+            return int(tok0)
 
     def admit_prefix(self, padded_suffix, slot, prefix_len, true_len,
                      bt_row, *, n_prefix_pages) -> int:
-        tok0, self.cache = self._admit_pref(
-            self.params, {"tokens": jnp.asarray(padded_suffix)}, self.cache,
-            jnp.int32(slot), jnp.int32(prefix_len), jnp.int32(true_len),
-            jnp.asarray(bt_row), n_prefix_pages=n_prefix_pages)
-        return int(tok0)
+        with self._spans.span("backend.prefill"):
+            tok0, self.cache = self._admit_pref(
+                self.params, {"tokens": jnp.asarray(padded_suffix)},
+                self.cache, jnp.int32(slot), jnp.int32(prefix_len),
+                jnp.int32(true_len), jnp.asarray(bt_row),
+                n_prefix_pages=n_prefix_pages)
+            return int(tok0)
 
     def prefill_chunk(self, padded_chunk, slot, prefix_len, true_len,
                       bt_row, *, n_prefix_pages) -> int:
@@ -412,31 +424,42 @@ class SingleDeviceBackend(PagedKVBackend):
                                  bt_row, n_prefix_pages=n_prefix_pages)
 
     def decode(self, tokens, active, lens=None):
+        # dispatch ends when the jitted call returns (inputs moved, the
+        # program enqueued); the wait is the device's compute and the
+        # results' copy back
+        span = self._spans.span
         if tokens.shape[1] == 1 and lens is None:
             # the pre-speculative path, byte-identical program: K=1 must
             # bitwise-reproduce the sequential engine
-            nxt, ok, self.cache = self._decode(
+            with span("backend.decode_dispatch"):
+                nxt, ok, self.cache = self._decode(
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(active))
+            with span("backend.decode_wait"):
+                return (np.asarray(nxt)[:, None],
+                        np.asarray(active, np.int32), np.asarray(ok))
+        with span("backend.decode_dispatch"):
+            out, n_emit, ok, self.cache = self._decode_window(
                 self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(active))
-            return (np.asarray(nxt)[:, None], np.asarray(active, np.int32),
-                    np.asarray(ok))
-        out, n_emit, ok, self.cache = self._decode_window(
-            self.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(active), jnp.asarray(lens))
-        return np.asarray(out), np.asarray(n_emit), np.asarray(ok)
+                jnp.asarray(active), jnp.asarray(lens))
+        with span("backend.decode_wait"):
+            return np.asarray(out), np.asarray(n_emit), np.asarray(ok)
 
     def copy_page(self, src_page: int, dst_page: int) -> None:
-        self.cache = pc.copy_page(self.cache, src_page, dst_page)
+        with self._spans.span("backend.page_table"):
+            self.cache = pc.copy_page(self.cache, src_page, dst_page)
 
     def release_slot(self, slot: int) -> None:
-        self.cache = pc.release_slot(self.cache, slot)
+        with self._spans.span("backend.page_table"):
+            self.cache = pc.release_slot(self.cache, slot)
 
     def write_block_entries(self, updates) -> None:
-        rows = jnp.asarray([u[0] for u in updates], jnp.int32)
-        cols = jnp.asarray([u[1] for u in updates], jnp.int32)
-        vals = jnp.asarray([u[2] for u in updates], jnp.int32)
-        bt = self.cache["block_tables"]
-        self.cache["block_tables"] = bt.at[rows, cols].set(vals)
+        with self._spans.span("backend.page_table"):
+            rows = jnp.asarray([u[0] for u in updates], jnp.int32)
+            cols = jnp.asarray([u[1] for u in updates], jnp.int32)
+            vals = jnp.asarray([u[2] for u in updates], jnp.int32)
+            bt = self.cache["block_tables"]
+            self.cache["block_tables"] = bt.at[rows, cols].set(vals)
 
     @staticmethod
     def _pad_page_vec(page_ids) -> np.ndarray:
@@ -450,26 +473,29 @@ class SingleDeviceBackend(PagedKVBackend):
         return pv
 
     def swap_out(self, page_ids) -> Any:
-        k = len(page_ids)
-        pv = self._pad_page_vec(page_ids)
-        rows = _gather_pages_fn(self.cache, jnp.asarray(pv))
-        # device_get assembles sharded pools from their addressable
-        # shards host-side — each device ships only its KV-head slice,
-        # so the tp transfer is per-shard with no device collective
-        host = jax.device_get(rows)
-        if len(pv) != k:
-            host = jax.tree_util.tree_map(lambda a: a[:k].copy(), host)
-        return host
+        with self._spans.span("backend.swap"):
+            k = len(page_ids)
+            pv = self._pad_page_vec(page_ids)
+            rows = _gather_pages_fn(self.cache, jnp.asarray(pv))
+            # device_get assembles sharded pools from their addressable
+            # shards host-side — each device ships only its KV-head
+            # slice, so the tp transfer is per-shard with no collective
+            host = jax.device_get(rows)
+            if len(pv) != k:
+                host = jax.tree_util.tree_map(lambda a: a[:k].copy(), host)
+            return host
 
     def swap_in(self, blob, page_ids) -> None:
-        k = len(page_ids)
-        pv = self._pad_page_vec(page_ids)
-        if len(pv) != k:
-            pad = len(pv) - k
-            blob = jax.tree_util.tree_map(
-                lambda a: np.concatenate(
-                    [a, np.zeros((pad,) + a.shape[1:], a.dtype)]), blob)
-        self.cache = _scatter_pages_fn(self.cache, blob, jnp.asarray(pv))
+        with self._spans.span("backend.swap"):
+            k = len(page_ids)
+            pv = self._pad_page_vec(page_ids)
+            if len(pv) != k:
+                pad = len(pv) - k
+                blob = jax.tree_util.tree_map(
+                    lambda a: np.concatenate(
+                        [a, np.zeros((pad,) + a.shape[1:], a.dtype)]), blob)
+            self.cache = _scatter_pages_fn(self.cache, blob,
+                                           jnp.asarray(pv))
 
     def host_page_bytes(self) -> int:
         return sum(int(leaf.nbytes) // int(leaf.shape[0])

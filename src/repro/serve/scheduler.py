@@ -102,6 +102,10 @@ page copies, slot release, block-table writes — goes through a
 reproduces the one-device behaviour; ``ShardedPagedBackend`` runs the
 same host logic over a KV-head-sharded, tensor-parallel page pool with
 token-for-token identical output (tests/test_serve_backend_multidevice).
+
+Each phase of ``step()`` and each backend call is a span
+(``serve/spans.py``): a profiler annotation on the device ops' clock,
+and its self time and call count among the engine's ``stats``.
 """
 from __future__ import annotations
 
@@ -115,6 +119,7 @@ import numpy as np
 from repro.core.model_config import ModelSpec
 from repro.serve import paged_cache as pc
 from repro.serve.backend import PagedKVBackend, SingleDeviceBackend
+from repro.serve.spans import SpanRecorder
 from repro.serve.spec_decode import NGramDraftTable
 
 
@@ -349,7 +354,7 @@ class ContinuousBatchingEngine:
             "iterations": 0, "decode_tokens": 0, "prefill_tokens": 0,
             "prompt_tokens": 0, "prefix_hit_tokens": 0, "admitted": 0,
             "finished": 0, "preemptions": 0, "cow_copies": 0,
-            "prefix_evicted_pages": 0, "occupancy_sum": 0.0,
+            "occupancy_sum": 0.0,
             # speculative decode: windows with >= 1 drafted token,
             # drafted-token count, and how many of them were accepted
             # (measured acceptance = spec_accepted / spec_drafted)
@@ -370,13 +375,12 @@ class ContinuousBatchingEngine:
             # host-tier swapping: pressure swap-outs of live victims,
             # swap-in resumes, pages moved each way, idle sessions
             # parked/dropped, and live in-place session reattaches.
-            # session_prompt/hit tokens count session rejoins separately
-            # from prefix_hit_tokens (the hit never touched the store)
-            # and recompute_* (nothing was recomputed)
+            # session_hit_tokens counts session rejoins separately from
+            # prefix_hit_tokens (the hit never touched the store) and
+            # recompute_* (nothing was recomputed)
             "swap_outs": 0, "swap_ins": 0, "swapped_out_pages": 0,
             "swapped_in_pages": 0, "idle_parks": 0, "idle_drops": 0,
-            "session_reuses": 0, "session_prompt_tokens": 0,
-            "session_hit_tokens": 0,
+            "session_reuses": 0, "session_hit_tokens": 0,
             # ring KV: exclusively-owned pages recycled in place as
             # they fell out of the window (each one is an allocation —
             # and a potential preemption — the flat engine would have
@@ -384,6 +388,11 @@ class ContinuousBatchingEngine:
             # released for a fresh one (prefix store / other holders
             # kept the bytes; nothing was stolen)
             "ring_recycled_pages": 0, "ring_shared_released": 0}
+        # self time and calls of each phase of a step and each backend
+        # call, as ``self_s:<span>`` / ``calls:<span>`` keys of stats
+        # (serve/spans.py); every span is also a profiler annotation
+        self.spans = SpanRecorder(self.stats)
+        self.backend.bind_spans(self.spans)
         # injectable wall clock for deadline shedding (tests freeze it)
         self.clock = time.monotonic
 
@@ -584,8 +593,7 @@ class ContinuousBatchingEngine:
         if self.alloc.can_alloc(n):
             return True
         if self.prefix_cache is not None:
-            self.stats["prefix_evicted_pages"] += self.prefix_cache.evict(
-                n - self.alloc.free_pages)
+            self.prefix_cache.evict(n - self.alloc.free_pages)
         if not self.alloc.can_alloc(n):
             for i in self._idle_slots_lru():
                 self._park_idle(i)
@@ -832,27 +840,29 @@ class ContinuousBatchingEngine:
             if quota == 0:
                 break
             slot = self.slots[i]
-            chunk = min(slot.prompt_len - slot.prefilled, quota)
-            spad = _bucket(chunk, page, self.cfg.max_seq)
-            padded = np.zeros((1, spad), np.int32)
-            padded[0, :chunk] = slot.prompt[
-                slot.prefilled:slot.prefilled + chunk]
-            row = np.full((row_len,), pc.NULL_PAGE, np.int32)
-            row[:len(slot.pages)] = slot.pages
-            # ring engines gather the WHOLE ring (the entry↔absolute-
-            # page mapping is mod-R over all entries); flat engines
-            # bucket the written-prefix width for compile reuse
-            npp = (row_len if self.ring else
-                   _pow2_pages(pc.pages_needed(slot.prefilled, page),
-                               row_len))
-            tok0 = self.backend.prefill_chunk(
-                padded, i, slot.prefilled, chunk, row, n_prefix_pages=npp)
-            slot.prefilled += chunk
-            budget -= spad
-            self.stats["prefill_tokens"] += chunk
-            self.stats["prefill_chunks"] += 1
-            if not slot.prefilling:
-                self._complete_prefill(slot, tok0)
+            with self.spans.span("engine.admit", uid=slot.uid):
+                chunk = min(slot.prompt_len - slot.prefilled, quota)
+                spad = _bucket(chunk, page, self.cfg.max_seq)
+                padded = np.zeros((1, spad), np.int32)
+                padded[0, :chunk] = slot.prompt[
+                    slot.prefilled:slot.prefilled + chunk]
+                row = np.full((row_len,), pc.NULL_PAGE, np.int32)
+                row[:len(slot.pages)] = slot.pages
+                # ring engines gather the WHOLE ring (the entry↔absolute-
+                # page mapping is mod-R over all entries); flat engines
+                # bucket the written-prefix width for compile reuse
+                npp = (row_len if self.ring else
+                       _pow2_pages(pc.pages_needed(slot.prefilled, page),
+                                   row_len))
+                tok0 = self.backend.prefill_chunk(
+                    padded, i, slot.prefilled, chunk, row,
+                    n_prefix_pages=npp)
+                slot.prefilled += chunk
+                budget -= spad
+                self.stats["prefill_tokens"] += chunk
+                self.stats["prefill_chunks"] += 1
+                if not slot.prefilling:
+                    self._complete_prefill(slot, tok0)
         return budget
 
     def _first_chunk(self, i: int, budget: Optional[int],
@@ -907,6 +917,13 @@ class ContinuousBatchingEngine:
         i = self._find_idle(req.session)
         if i is None:
             return budget
+        with self.spans.span("engine.admit", uid=req.uid):
+            return self._rejoin_idle(i, req, budget)
+
+    def _rejoin_idle(self, i: int, req: Request,
+                     budget: Optional[int]) -> Optional[int]:
+        """The in-place rejoin of ``_try_resume_idle``: the queue head
+        ``req`` extends the context of idle slot ``i``."""
         slot = self.slots[i]
         ctx = slot.prompt_len + len(slot.generated)
         plen = len(req.prompt)
@@ -950,7 +967,6 @@ class ContinuousBatchingEngine:
         self._admit_seq += 1
         self.stats["admitted"] += 1
         self.stats["session_reuses"] += 1
-        self.stats["session_prompt_tokens"] += plen
         self.stats["session_hit_tokens"] += written
         try:
             return self._first_chunk(i, budget, written)
@@ -1043,7 +1059,6 @@ class ContinuousBatchingEngine:
             self.stats["recompute_prompt_tokens"] += plen
             self.stats["recompute_hit_tokens"] += rec.written
         else:
-            self.stats["session_prompt_tokens"] += plen
             self.stats["session_hit_tokens"] += rec.written
         try:
             budget = self._first_chunk(i, budget, rec.written)
@@ -1055,8 +1070,6 @@ class ContinuousBatchingEngine:
         return ("admitted", budget)
 
     def _admit(self) -> None:
-        page = self.cfg.page_size
-        row_len = self.layout.slots_pages(self.cfg.max_seq)
         budget = (self.cfg.prefill_chunk_tokens
                   if self.cfg.prefill_chunk_tokens else None)
         budget = self._continue_prefills(budget)
@@ -1070,147 +1083,159 @@ class ContinuousBatchingEngine:
             if (req.session is not None and req.uid not in self._resume
                     and self._find_idle(req.session) is not None):
                 break   # head rejoins its live idle slot once budget allows
-            key = self._parked_key(req)
-            if key is not None:
-                status, budget = self._admit_swapped(i, req, key, budget)
-                if status == "admitted":
-                    continue
-                if status == "wait":
-                    break             # FCFS: don't starve the head
-                # "miss": record dropped — fall through to cold admission
-            plen = len(req.prompt)
-            n_prompt_pages = pc.pages_needed(plen, page)
-            # ring KV: the slot holds at most the ring capacity — a
-            # prompt wider than that wraps over its own entries during
-            # prefill (the scatter routes below-horizon rows to the
-            # null page), so admission allocates O(window) pages however
-            # long the prompt
-            n_slot_pages = (min(n_prompt_pages, row_len) if self.ring
-                            else n_prompt_pages)
-            match = (self.prefix_cache.lookup(req.prompt)
-                     if self.prefix_cache is not None
-                     else pc.PrefixMatch([], None, 0))
-            if self.ring and n_prompt_pages > row_len:
-                # the prompt wraps before prefill completes: matched
-                # flat prefix pages cannot sit at ring entries (entry j
-                # must end up holding the LAST absolute page ≡ j mod R)
-                # — skip reuse rather than install a wrong layout
-                match = pc.PrefixMatch([], None, 0)
-            # Try the richest reuse first; with live slots a failed
-            # reserve just WAITS (they finish and free pages, and the
-            # matched entries survive for the retry).  With NO live
-            # slots nothing will ever free pages, so waiting would
-            # livelock when the pins themselves make the last needed
-            # pages unevictable — degrade instead: dropping the
-            # partial, then the full match, releases those pins so
-            # `_reserve` can evict them as plain store pages (submit()
-            # guarantees the no-reuse plan fits solo, so the ladder
-            # terminates).
-            attempts = [(match.full_pages, match.partial, match.tokens)]
-            if self.num_active == 0:
-                if match.partial is not None:
-                    attempts.append((match.full_pages, None,
-                                     len(match.full_pages) * page))
-                if match.full_pages:
-                    attempts.append(([], None, 0))
-            # headroom: one page per live slot, so a fresh admission
-            # can't grab the exact pages an older slot's next page-
-            # boundary crossing needs (which would make the newcomer
-            # the immediate preemption victim and burn its prefill)
-            headroom = self.num_active
-            plan = None
-            for full_pages, partial, matched in attempts:
-                pinned = list(full_pages)
-                if partial is not None:
-                    pinned.append(partial[0])
-                if pinned:
-                    self.alloc.share(pinned)
-                fresh_needed = n_slot_pages - len(full_pages)
-                if self._reserve(fresh_needed + headroom):
-                    plan = (full_pages, partial, matched, fresh_needed)
-                    break
-                if pinned:
-                    self.alloc.free(pinned)
-            if plan is None:
-                break                     # FCFS: don't starve the head
-            full_pages, partial, matched, fresh_needed = plan
-            self.queue.popleft()
-            fresh = self.alloc.alloc(fresh_needed)
-            pages = full_pages + fresh
-            cow_src = partial[0] if partial is not None else None
-            try:
-                if partial is not None:
-                    self.backend.copy_page(cow_src, fresh[0])
-                    self.alloc.free([cow_src])  # drop the temp CoW pin
-                    cow_src = None
-                    self.stats["cow_copies"] += 1
+            with self.spans.span("engine.admit", uid=req.uid):
+                status, budget = self._admit_head(i, req, budget)
+            if status == "wait":
+                break                 # FCFS: don't starve the head
 
-                row = np.full((row_len,), pc.NULL_PAGE, np.int32)
-                row[:len(pages)] = pages
-                suffix_len = plen - matched
-                # first prefill chunk this iteration: the whole suffix
-                # when unbudgeted (or it fits), else the widest bucket
-                # the remaining budget buys — the rest carries across
-                # iterations
-                chunk = (suffix_len if budget is None
-                         else min(suffix_len, self._chunk_quota(budget)))
-                if chunk == suffix_len and matched == 0:
-                    spad = _bucket(plen, page, self.cfg.max_seq)
-                    assert spad // page >= n_prompt_pages, \
-                        "bucket narrower than the prompt's pages"
-                    padded = np.zeros((1, spad), np.int32)
-                    padded[0, :plen] = req.prompt
-                    tok0 = self.backend.admit_full(padded, i, plen, row)
-                else:
-                    spad = _bucket(chunk, page, self.cfg.max_seq)
-                    padded = np.zeros((1, spad), np.int32)
-                    padded[0, :chunk] = req.prompt[matched:matched + chunk]
-                    npp = (row_len if self.ring else
-                           _pow2_pages(pc.pages_needed(matched, page),
-                                       row_len))
-                    tok0 = (self.backend.admit_prefix(
-                                padded, i, matched, chunk, row,
-                                n_prefix_pages=npp)
-                            if chunk == suffix_len else
-                            self.backend.prefill_chunk(
-                                padded, i, matched, chunk, row,
-                                n_prefix_pages=npp))
-            except Exception:
-                # zero-lost invariant: a backend dying MID-ADMISSION
-                # must not strand the popped request — restore it to
-                # the queue head and return every page ref this
-                # admission took, then surface the fault to the
-                # router's health check
-                if cow_src is not None:
-                    self.alloc.free([cow_src])
-                self.alloc.free(pages)
-                self.queue.appendleft(req)
-                raise
-            if budget is not None:
-                budget -= spad
-            slot = _Slot(req.uid, req.prompt, plen, req.max_new_tokens,
-                         pages, -1, self._admit_seq, [], None,
-                         prefilled=matched + chunk,
-                         deadline_s=req.deadline_s, retries_left=req.retries,
-                         arrival_t=req.arrival_t, session=req.session,
-                         abs_pages=n_prompt_pages)
-            self.slots[i] = slot
-            self._admit_seq += 1
-            self.stats["admitted"] += 1
-            self.stats["prefill_tokens"] += chunk
-            if req.uid in self._resume:
-                # recompute re-prefill: the prompt includes prior output
-                # and the match mostly re-hits this request's own pages
-                # — keep it out of the honest prompt/hit-rate counters
-                self.stats["recompute_prompt_tokens"] += plen
-                self.stats["recompute_hit_tokens"] += matched
+    def _admit_head(self, i: int, req: Request, budget: Optional[int]
+                    ) -> Tuple[str, Optional[int]]:
+        """Admit the queue head ``req`` into free slot ``i``: swap it in
+        when its KV is parked, else prefill it cold or over its cached
+        prefix.  Returns "admitted" or "wait" (FCFS — pages short, retry
+        next iteration) with the remaining budget."""
+        page = self.cfg.page_size
+        row_len = self.layout.slots_pages(self.cfg.max_seq)
+        key = self._parked_key(req)
+        if key is not None:
+            status, budget = self._admit_swapped(i, req, key, budget)
+            if status != "miss":
+                return status, budget
+            # "miss": record dropped — fall through to cold admission
+        plen = len(req.prompt)
+        n_prompt_pages = pc.pages_needed(plen, page)
+        # ring KV: the slot holds at most the ring capacity — a
+        # prompt wider than that wraps over its own entries during
+        # prefill (the scatter routes below-horizon rows to the
+        # null page), so admission allocates O(window) pages however
+        # long the prompt
+        n_slot_pages = (min(n_prompt_pages, row_len) if self.ring
+                        else n_prompt_pages)
+        match = (self.prefix_cache.lookup(req.prompt)
+                 if self.prefix_cache is not None
+                 else pc.PrefixMatch([], None, 0))
+        if self.ring and n_prompt_pages > row_len:
+            # the prompt wraps before prefill completes: matched
+            # flat prefix pages cannot sit at ring entries (entry j
+            # must end up holding the LAST absolute page ≡ j mod R)
+            # — skip reuse rather than install a wrong layout
+            match = pc.PrefixMatch([], None, 0)
+        # Try the richest reuse first; with live slots a failed
+        # reserve just WAITS (they finish and free pages, and the
+        # matched entries survive for the retry).  With NO live
+        # slots nothing will ever free pages, so waiting would
+        # livelock when the pins themselves make the last needed
+        # pages unevictable — degrade instead: dropping the
+        # partial, then the full match, releases those pins so
+        # `_reserve` can evict them as plain store pages (submit()
+        # guarantees the no-reuse plan fits solo, so the ladder
+        # terminates).
+        attempts = [(match.full_pages, match.partial, match.tokens)]
+        if self.num_active == 0:
+            if match.partial is not None:
+                attempts.append((match.full_pages, None,
+                                 len(match.full_pages) * page))
+            if match.full_pages:
+                attempts.append(([], None, 0))
+        # headroom: one page per live slot, so a fresh admission
+        # can't grab the exact pages an older slot's next page-
+        # boundary crossing needs (which would make the newcomer
+        # the immediate preemption victim and burn its prefill)
+        headroom = self.num_active
+        plan = None
+        for full_pages, partial, matched in attempts:
+            pinned = list(full_pages)
+            if partial is not None:
+                pinned.append(partial[0])
+            if pinned:
+                self.alloc.share(pinned)
+            fresh_needed = n_slot_pages - len(full_pages)
+            if self._reserve(fresh_needed + headroom):
+                plan = (full_pages, partial, matched, fresh_needed)
+                break
+            if pinned:
+                self.alloc.free(pinned)
+        if plan is None:
+            return "wait", budget     # FCFS: don't starve the head
+        full_pages, partial, matched, fresh_needed = plan
+        self.queue.popleft()
+        fresh = self.alloc.alloc(fresh_needed)
+        pages = full_pages + fresh
+        cow_src = partial[0] if partial is not None else None
+        try:
+            if partial is not None:
+                self.backend.copy_page(cow_src, fresh[0])
+                self.alloc.free([cow_src])  # drop the temp CoW pin
+                cow_src = None
+                self.stats["cow_copies"] += 1
+
+            row = np.full((row_len,), pc.NULL_PAGE, np.int32)
+            row[:len(pages)] = pages
+            suffix_len = plen - matched
+            # first prefill chunk this iteration: the whole suffix
+            # when unbudgeted (or it fits), else the widest bucket
+            # the remaining budget buys — the rest carries across
+            # iterations
+            chunk = (suffix_len if budget is None
+                     else min(suffix_len, self._chunk_quota(budget)))
+            if chunk == suffix_len and matched == 0:
+                spad = _bucket(plen, page, self.cfg.max_seq)
+                assert spad // page >= n_prompt_pages, \
+                    "bucket narrower than the prompt's pages"
+                padded = np.zeros((1, spad), np.int32)
+                padded[0, :plen] = req.prompt
+                tok0 = self.backend.admit_full(padded, i, plen, row)
             else:
-                self.stats["prompt_tokens"] += plen
-                self.stats["prefix_hit_tokens"] += matched
-            if slot.prefilling:
-                self.stats["prefill_chunks"] += 1
-            else:
-                self._complete_prefill(slot, tok0)
+                spad = _bucket(chunk, page, self.cfg.max_seq)
+                padded = np.zeros((1, spad), np.int32)
+                padded[0, :chunk] = req.prompt[matched:matched + chunk]
+                npp = (row_len if self.ring else
+                       _pow2_pages(pc.pages_needed(matched, page),
+                                   row_len))
+                tok0 = (self.backend.admit_prefix(
+                            padded, i, matched, chunk, row,
+                            n_prefix_pages=npp)
+                        if chunk == suffix_len else
+                        self.backend.prefill_chunk(
+                            padded, i, matched, chunk, row,
+                            n_prefix_pages=npp))
+        except Exception:
+            # zero-lost invariant: a backend dying MID-ADMISSION
+            # must not strand the popped request — restore it to
+            # the queue head and return every page ref this
+            # admission took, then surface the fault to the
+            # router's health check
+            if cow_src is not None:
+                self.alloc.free([cow_src])
+            self.alloc.free(pages)
+            self.queue.appendleft(req)
+            raise
+        if budget is not None:
+            budget -= spad
+        slot = _Slot(req.uid, req.prompt, plen, req.max_new_tokens,
+                     pages, -1, self._admit_seq, [], None,
+                     prefilled=matched + chunk,
+                     deadline_s=req.deadline_s, retries_left=req.retries,
+                     arrival_t=req.arrival_t, session=req.session,
+                     abs_pages=n_prompt_pages)
+        self.slots[i] = slot
+        self._admit_seq += 1
+        self.stats["admitted"] += 1
+        self.stats["prefill_tokens"] += chunk
+        if req.uid in self._resume:
+            # recompute re-prefill: the prompt includes prior output
+            # and the match mostly re-hits this request's own pages
+            # — keep it out of the honest prompt/hit-rate counters
+            self.stats["recompute_prompt_tokens"] += plen
+            self.stats["recompute_hit_tokens"] += matched
+        else:
+            self.stats["prompt_tokens"] += plen
+            self.stats["prefix_hit_tokens"] += matched
+        if slot.prefilling:
+            self.stats["prefill_chunks"] += 1
+        else:
+            self._complete_prefill(slot, tok0)
+        return "admitted", budget
 
     def _ring_extend(self, slot: _Slot, need_abs: int,
                      updates: Optional[List[tuple]] = None,
@@ -1409,7 +1434,8 @@ class ContinuousBatchingEngine:
         fresh page — and, under speculation, every slot's drafted window
         width (a verify step scatters up to ``spec_k`` rows).
         """
-        completions = self._step_impl()
+        with self.spans.span("engine.step"):
+            completions = self._step_impl()
         if self.cfg.debug_invariants:
             self.check_invariants()
         return completions
@@ -1436,14 +1462,28 @@ class ContinuousBatchingEngine:
                 self._park_idle(i)
 
     def _step_impl(self) -> List[Completion]:
+        span = self.spans.span
         completions: List[Completion] = []
         self._shed_expired(completions)   # deadline-expired queued work
         self._park_idle_expired()         # idle sessions past the timer
-        self._grow()                      # may preempt; slots can change
-        self._admit()
-        self._finish(completions)         # max_new == 1 finishes at prefill
+        with span("engine.grow"):
+            self._grow()                  # may preempt; slots can change
+        self._admit()                     # an engine.admit span per request
+        with span("engine.finish"):
+            self._finish(completions)     # max_new == 1 finishes at prefill
         if self.num_active == 0:
             return completions
+        with span("engine.decode"):
+            decoded = self._decode(completions)
+        if decoded:
+            with span("engine.finish"):
+                self._finish(completions)
+        return completions
+
+    def _decode(self, completions: List[Completion]) -> bool:
+        """Draft a window per live slot, grow their pages, run one
+        batched decode step and commit what it emitted.  False when no
+        slot had a window to decode."""
         K = max(1, self.cfg.spec_k)
         # draft a window per live slot: the last committed token plus up
         # to K-1 prompt-lookup drafts, capped by the remaining budget so
@@ -1457,7 +1497,8 @@ class ContinuousBatchingEngine:
             if K > 1 and slot.draft is not None and rem > 1:
                 win += slot.draft.propose(min(K, rem) - 1)
             windows[i] = win
-        self._grow(window={i: len(w) for i, w in windows.items()})
+        with self.spans.span("engine.grow"):
+            self._grow(window={i: len(w) for i, w in windows.items()})
         B = self.cfg.max_slots
         tokens = np.zeros((B, K), np.int32)
         active = np.zeros((B,), np.int32)
@@ -1472,7 +1513,7 @@ class ContinuousBatchingEngine:
             lens[i] = len(win)
             active[i] = 1
         if not active.any():
-            return completions
+            return False
         out, n_emit, okf = (self.backend.decode(tokens, active) if K == 1
                             else self.backend.decode(tokens, active, lens))
         for i, slot in enumerate(self.slots):
@@ -1497,8 +1538,7 @@ class ContinuousBatchingEngine:
         usable = self.layout.num_pages - 1
         self.stats["occupancy_sum"] += (usable - self.alloc.free_pages) / usable
         self.stats["iterations"] += 1
-        self._finish(completions)
-        return completions
+        return True
 
     def run(self, requests: List[Request]) -> List[Completion]:
         """Drain a whole workload; completions come back sorted by uid."""

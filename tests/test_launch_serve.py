@@ -4,6 +4,7 @@ backend-factory signature change) only surfaced when a human ran it.
 Each test drives the real argparse entry point in a subprocess on a
 tiny --local config and asserts on the printed report."""
 import os
+import re
 import subprocess
 import sys
 
@@ -35,6 +36,9 @@ def test_paged_engine_cli_int4():
     out = _serve(*TINY, "--engine", "paged", "--cache-dtype", "int4")
     assert "paged engine (int4 pages" in out
     assert "usable pages" in out
+    assert re.search(r"host per step: engine \d+\.\d+ ms, page table "
+                     r"\d+\.\d+ ms; decode dispatch \d+\.\d+ ms per call",
+                     out), out
 
 
 def test_paged_engine_cli_spec_decode():
