@@ -103,8 +103,8 @@ entries: per-slot KV is O(window) for UNBOUNDED streams, the write
 head recycles an exclusive out-of-window page in place (zero allocator
 traffic) and releases — never frees — a shared prefix page that falls
 out of the window, and the Pallas kernels stream only the ring's
-entries (flat windowed tables get the same O(window) traffic via the
-page-skip index map).  ``SchedulerConfig.windowed_kv``: ``None``
+entries (flat windowed tables get the same O(window) traffic: the
+kernel streams only a slot's last O(window) live entries).  ``SchedulerConfig.windowed_kv``: ``None``
 auto-detects, ``False`` forces the mask-only reference (same windowed
 attention math, full-attention memory — the ``--window`` gate
 baseline), ``True`` asserts the stack qualifies.  Sessions park/rejoin

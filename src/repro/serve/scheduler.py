@@ -117,6 +117,7 @@ from typing import Any, Deque, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core.model_config import ModelSpec
+from repro.kernels.paged_attention import live_entries
 from repro.serve import paged_cache as pc
 from repro.serve.backend import PagedKVBackend, SingleDeviceBackend
 from repro.serve.spans import SpanRecorder
@@ -359,6 +360,10 @@ class ContinuousBatchingEngine:
             # drafted-token count, and how many of them were accepted
             # (measured acceptance = spec_accepted / spec_drafted)
             "spec_steps": 0, "spec_drafted": 0, "spec_accepted": 0,
+            # decode attention: block-table pages the paged kernel
+            # streams (kernels.paged_attention.live_entries) against
+            # the table it could walk (slots x entries), per decode call
+            "kv_pages_streamed": 0, "kv_pages_table": 0,
             # recompute re-prefills (preemption resumes) count here, NOT
             # in prompt_tokens/prefix_hit_tokens: a resumed prompt
             # includes prior OUTPUT and mostly re-hits its own pages, so
@@ -1514,6 +1519,7 @@ class ContinuousBatchingEngine:
             active[i] = 1
         if not active.any():
             return False
+        self._count_kv_pages(windows, K)
         out, n_emit, okf = (self.backend.decode(tokens, active) if K == 1
                             else self.backend.decode(tokens, active, lens))
         for i, slot in enumerate(self.slots):
@@ -1539,6 +1545,23 @@ class ContinuousBatchingEngine:
         self.stats["occupancy_sum"] += (usable - self.alloc.free_pages) / usable
         self.stats["iterations"] += 1
         return True
+
+    def _count_kv_pages(self, windows: Dict[int, List[int]], K: int):
+        """Add this decode call's pages streamed and pages in the block
+        table to ``stats``.  The kernel sees each slot's context through
+        its K-wide window: ``write_pos + K`` for a decoding slot, ``K``
+        for the rest (the step pins them at position 0)."""
+        lengths = np.full(self.cfg.max_slots, K, np.int64)
+        for i in windows:
+            slot = self.slots[i]
+            if slot is not None and not slot.done:
+                lengths[i] = slot.prompt_len + len(slot.generated) - 1 + K
+        n_entries = self.layout.slots_pages(self.cfg.max_seq)
+        streamed = live_entries(lengths, self.cfg.page_size, n_entries,
+                                window=self.window, ring=self.ring,
+                                wq=K if K > 1 else 0)
+        self.stats["kv_pages_streamed"] += int(streamed.sum())
+        self.stats["kv_pages_table"] += len(lengths) * n_entries
 
     def run(self, requests: List[Request]) -> List[Completion]:
         """Drain a whole workload; completions come back sorted by uid."""

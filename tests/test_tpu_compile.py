@@ -5,7 +5,10 @@ The TPU compiler is installed next to the CPU backend, so these tests
 refuse what interpret mode accepts (unaligned slices, VMEM overuse) at
 no chip time.  The paged-attention kernel is compiled at TinyLlama-1.1B
 decode widths: 8 slots, 32 query heads over 4 KV heads of 64, 16-token
-pages, for fp32, int8 and int4 pages and a 4-token verify window.
+pages, for fp32, int8 and int4 pages and a 4-token verify window; and at
+the chip benchmark's widths (32 slots of 256 pages over an 8,193-page
+pool): DeepSeek-R1-Distill-Qwen-1.5B with fp32 pages and Granite-3.0-8B
+with int4 pages, whose streamed blocks have to fit the scoped VMEM.
 
 The topology is described inside a fixture of this file only: loading
 the TPU library at import time, or from a second file, would make the
@@ -43,18 +46,19 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _args(one_chip, cache_dtype, window_k):
+def _args(one_chip, cache_dtype, window_k, b=B, h=H, kv=KV, d=D, pool=POOL,
+          pages_per_slot=PAGES_PER_SLOT):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    q = sds((B, window_k, H, D) if window_k else (B, H, D), jnp.float32)
+    q = sds((b, window_k, h, d) if window_k else (b, h, d), jnp.float32)
     tok = PAGE // 2 if cache_dtype == "int4" else PAGE
     pool_dtype = jnp.float32 if cache_dtype == "fp32" else jnp.int8
-    pages = sds((POOL, tok, KV, D), pool_dtype)
-    tables = sds((B, PAGES_PER_SLOT), jnp.int32)
-    lengths = sds((B,), jnp.int32)
+    pages = sds((pool, tok, kv, d), pool_dtype)
+    tables = sds((b, pages_per_slot), jnp.int32)
+    lengths = sds((b,), jnp.int32)
     scales = {}
     if cache_dtype != "fp32":
-        scale = sds((POOL, KV, PAGE), jnp.float32)
+        scale = sds((pool, kv, PAGE), jnp.float32)
         scales = {"k_scale": scale, "v_scale": scale}
     return (q, pages, pages, tables, lengths), scales
 
@@ -63,6 +67,17 @@ def _args(one_chip, cache_dtype, window_k):
     ("fp32", 0), ("int8", 0), ("int4", 0), ("int4", 4)])
 def test_paged_attention_compiles_for_v5e(one_chip, cache_dtype, window_k):
     args, scales = _args(one_chip, cache_dtype, window_k)
+    kernel = functools.partial(paged_attention_pallas, interpret=False)
+    compiled = jax.jit(kernel).lower(*args, **scales).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("cache_dtype,h,kv", [("fp32", 12, 2),    # R1 1.5B
+                                              ("int4", 32, 8)])   # Granite
+def test_paged_attention_compiles_at_benchmark_widths(one_chip, cache_dtype,
+                                                      h, kv):
+    args, scales = _args(one_chip, cache_dtype, 0, b=32, h=h, kv=kv, d=128,
+                         pool=32 * 256 + 1, pages_per_slot=256)
     kernel = functools.partial(paged_attention_pallas, interpret=False)
     compiled = jax.jit(kernel).lower(*args, **scales).compile()
     assert "tpu_custom_call" in compiled.as_text()
