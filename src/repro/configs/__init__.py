@@ -10,6 +10,7 @@ from repro.configs.gemma3_4b import SPEC as GEMMA3_4B
 from repro.configs.glm4_9b import SPEC as GLM4_9B
 from repro.configs.granite_3_8b import SPEC as GRANITE_3_8B
 from repro.configs.internvl2_2b import SPEC as INTERNVL2_2B
+from repro.configs.lfm2_8b_a1b import SPEC as LFM2_8B_A1B
 from repro.configs.llama4_scout_17b_a16e import SPEC as LLAMA4_SCOUT
 from repro.configs.minitron_4b import SPEC as MINITRON_4B
 from repro.configs.qwen2_moe_a2_7b import SPEC as QWEN2_MOE
@@ -24,7 +25,8 @@ ASSIGNED: Dict[str, ModelSpec] = {
     )
 }
 
-ARCHS: Dict[str, ModelSpec] = {**ASSIGNED, **EDGE_MODELS}
+ARCHS: Dict[str, ModelSpec] = {**ASSIGNED, **EDGE_MODELS,
+                               LFM2_8B_A1B.name: LFM2_8B_A1B}
 SHAPES: Dict[str, ShapeSpec] = dict(_shapes.SHAPES)
 
 # long_500k requires sub-quadratic attention (DESIGN.md §7 skip table).

@@ -257,6 +257,41 @@ def kv_budget(device_bytes: float, mem: MemoryBreakdown,
     return free
 
 
+def expected_experts_hit(spec: ModelSpec, rows: float) -> float:
+    """Expected distinct held experts one routed layer touches when
+    ``rows`` tokens are routed top-k over all ``num_experts`` uniformly:
+    each held expert is missed by a row with probability 1 - k/E, so
+    held * (1 - (1 - k/E)**rows)."""
+    m = spec.moe
+    if m is None:
+        return 0.0
+    return m.num_held * (1.0 - (1.0 - m.top_k / m.num_experts) ** rows)
+
+
+def streamed_params(spec: ModelSpec, rows: float) -> float:
+    """Parameters one step reads when ``rows`` tokens pass every layer:
+    all weights outside the routed experts (the leading dense layers'
+    FFNs among them), plus in each MoE layer only the held experts the
+    rows are expected to hit (``expected_experts_hit``).  A dense spec
+    reads its logical parameter count."""
+    P = blocks.param_count(spec, padded=False)
+    m = spec.moe
+    if m is None:
+        return float(P)
+    n_moe = sum(1 for i in range(spec.num_layers) if spec.moe_layer(i))
+    per_expert = 3 * spec.d_model * m.expert_ff
+    held = m.num_held if m.held is not None else m.num_experts
+    return float(P - n_moe * (held - expected_experts_hit(spec, rows))
+                 * per_expert)
+
+
+def conv_state_bytes(spec: ModelSpec, slots: int) -> float:
+    """Float32 per-slot state of the gated short-convolution layers:
+    the last ``conv_width - 1`` conv inputs of each conv layer."""
+    return (4.0 * slots * spec.num_conv_layers()
+            * max(spec.conv_width - 1, 0) * spec.d_model)
+
+
 def mixed_iteration_flops(spec: ModelSpec, prefill_tokens: int,
                           decode_slots: int, avg_context: float,
                           cached_prefix_tokens: int = 0) -> float:
@@ -488,8 +523,7 @@ def analyze(spec: ModelSpec, shape: ShapeSpec, precision: PrecisionSpec,
         coll.tp_act = per * 2 * n_tp_layers * (3 if is_train else 1)
     if spec.moe is not None and tp > 1:
         tok_dev = tokens / max(1, dpx)
-        n_moe = sum(1 for i, k in enumerate(spec.layer_kinds())
-                    if k.startswith("attn") and i % spec.moe_every == 0)
+        n_moe = sum(1 for i in range(spec.num_layers) if spec.moe_layer(i))
         coll.ep_a2a = (2 * tok_dev * spec.moe.top_k * d * ab * n_moe
                        * (3 if is_train else 1))
     if shape.kind == "decode" and B < dpx:

@@ -58,17 +58,33 @@ def moe_param_shapes(spec: ModelSpec) -> Dict[str, Shape]:
     m = spec.moe
     assert m is not None
     d = spec.d_model
-    ep = m.padded_experts
+    ep = m.num_held                  # the experts this chip holds
     out = {
         "router_w": (d, m.num_experts),
         "experts_wi": (ep, d, 2 * m.expert_ff),
         "experts_wo": (ep, m.expert_ff, d),
     }
+    if m.expert_bias:
+        out["expert_bias"] = (m.num_experts,)
     if m.num_shared_experts:
         sff = m.shared_ff or m.num_shared_experts * m.expert_ff
         out["shared_wi"] = (d, 2 * sff)
         out["shared_wo"] = (sff, d)
     return out
+
+
+def conv_param_shapes(spec: ModelSpec) -> Dict[str, Shape]:
+    """Gated short convolution: in-projection to [B, C, h], a depthwise
+    causal kernel of ``conv_width`` taps, out-projection (no biases)."""
+    d = spec.d_model
+    return {"conv_in": (d, 3 * d), "conv_w": (spec.conv_width, d),
+            "conv_out": (d, d)}
+
+
+def ffn_param_shapes(spec: ModelSpec, layer_idx: int) -> Dict[str, Shape]:
+    if spec.moe_layer(layer_idx):
+        return moe_param_shapes(spec)
+    return mlp_param_shapes(spec)
 
 
 def ssm_param_shapes(spec: ModelSpec) -> Dict[str, Shape]:
@@ -132,13 +148,17 @@ def layer_param_shapes(spec: ModelSpec, kind: str, layer_idx: int = 0) -> Dict[s
     if kind in ("attn", "attn_local", "attn_global"):
         out.update(norm_shapes(spec, ("norm1", "norm2")))
         out.update(attention_param_shapes(spec))
+        if spec.qk_norm:
+            out["q_norm"] = (spec.head_dim,)
+            out["k_norm"] = (spec.head_dim,)
         if spec.cross_attention:
             out.update(norm_shapes(spec, ("norm_cross",)))
             out.update(attention_param_shapes(spec, cross=True))
-        if spec.moe is not None and (layer_idx % spec.moe_every == 0):
-            out.update(moe_param_shapes(spec))
-        else:
-            out.update(mlp_param_shapes(spec))
+        out.update(ffn_param_shapes(spec, layer_idx))
+    elif kind == "conv":
+        out.update(norm_shapes(spec, ("norm1", "norm2")))
+        out.update(conv_param_shapes(spec))
+        out.update(ffn_param_shapes(spec, layer_idx))
     elif kind == "enc_attn":                      # encoder layer: non-causal attn + mlp
         out.update(norm_shapes(spec, ("norm1", "norm2")))
         out.update(attention_param_shapes(spec))
@@ -194,7 +214,8 @@ def param_count(spec: ModelSpec, padded: bool = True) -> int:
     for i, kind in enumerate(spec.layer_kinds()):
         for name, shape in layer_param_shapes(spec, kind, i).items():
             n = _prod(shape)
-            if not padded and spec.moe is not None and name.startswith("experts_"):
+            if (not padded and spec.moe is not None
+                    and spec.moe.held is None and name.startswith("experts_")):
                 n = n * spec.moe.num_experts // spec.moe.padded_experts
             total += n
     if spec.ssm is not None and spec.attn_every:
@@ -216,11 +237,22 @@ def active_param_count(spec: ModelSpec) -> int:
         return param_count(spec, padded=False)
     m = spec.moe
     total = param_count(spec, padded=False)
-    n_moe_layers = sum(1 for i, k in enumerate(spec.layer_kinds())
-                       if k.startswith("attn") and i % spec.moe_every == 0)
+    n_moe_layers = sum(1 for i in range(spec.num_layers) if spec.moe_layer(i))
     per_expert = _prod((spec.d_model, 2 * m.expert_ff)) + _prod((m.expert_ff, spec.d_model))
-    total -= n_moe_layers * (m.num_experts - m.top_k) * per_expert
+    # experts the parameter count holds less the top_k a token uses
+    # (with a held share, the expected top_k * held / num_experts)
+    held = m.num_held if m.held is not None else m.num_experts
+    total -= n_moe_layers * (held - m.top_k * held_share(spec)) * per_expert
     return total
+
+
+def held_share(spec: ModelSpec) -> float:
+    """Share of a token's routed experts this chip computes, under
+    uniform routing: held / num_experts with a held range, else 1."""
+    m = spec.moe
+    if m is None or m.held is None:
+        return 1.0
+    return m.num_held / m.num_experts
 
 
 # ---------------------------------------------------------------------------
@@ -270,11 +302,25 @@ def moe_flops_per_token(spec: ModelSpec, dispatch: bool = False,
         # dispatch/combine one-hot einsums: 2 * E * cap * d each
         f += 2 * 2 * m.top_k * m.capacity_factor * d
     else:
-        f += m.top_k * per_ff(m.expert_ff)
+        f += m.top_k * held_share(spec) * per_ff(m.expert_ff)
     if m.num_shared_experts:
         sff = m.shared_ff or m.num_shared_experts * m.expert_ff
         f += per_ff(sff)
     return f
+
+
+def conv_flops_per_token(spec: ModelSpec) -> float:
+    """Gated short convolution: in/out projections, the B*h and C*y
+    gates, and the depthwise kernel."""
+    d = spec.d_model
+    return 2 * d * 3 * d + 2 * d * d + 2 * d + 2 * spec.conv_width * d
+
+
+def ffn_flops_per_token(spec: ModelSpec, layer_idx: int,
+                        dispatch: bool = False) -> float:
+    if spec.moe_layer(layer_idx):
+        return moe_flops_per_token(spec, dispatch=dispatch)
+    return mlp_flops_per_token(spec)
 
 
 def ssm_flops_per_token(spec: ModelSpec) -> float:
@@ -321,11 +367,10 @@ def layer_flops_per_token(spec: ModelSpec, kind: str, s_ctx: int,
         f = attention_flops_per_token(
             spec, _ctx(spec, kind, s_ctx),
             cross_len=spec.encoder_seq if spec.cross_attention else 0)
-        if spec.moe is not None and (layer_idx % spec.moe_every == 0):
-            f += moe_flops_per_token(spec, dispatch=dispatch)
-        else:
-            f += mlp_flops_per_token(spec)
-        return f + 2 * norm_f
+        return f + ffn_flops_per_token(spec, layer_idx, dispatch) + 2 * norm_f
+    if kind == "conv":
+        return (conv_flops_per_token(spec)
+                + ffn_flops_per_token(spec, layer_idx, dispatch) + 2 * norm_f)
     if kind == "enc_attn":
         return (attention_flops_per_token(spec, s_ctx)
                 + mlp_flops_per_token(spec) + 2 * norm_f)
@@ -376,6 +421,8 @@ def layer_state_shapes(spec: ModelSpec, kind: str, batch: int, max_seq: int) -> 
         w = min(max_seq, spec.sliding_window or max_seq)
         return {"k": (batch, w, spec.num_kv_heads, spec.head_dim),
                 "v": (batch, w, spec.num_kv_heads, spec.head_dim)}
+    if kind == "conv":
+        return {"conv_state": (batch, spec.conv_width - 1, spec.d_model)}
     if kind == "ssm":
         s = spec.ssm
         d_inner = s.expand * spec.d_model

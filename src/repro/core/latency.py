@@ -12,9 +12,11 @@ from dataclasses import dataclass
 from typing import Dict
 
 from repro.core.analytical import (Analysis, PagedCachePlan,
-                                   effective_slots, expected_accepted_tokens,
+                                   conv_state_bytes, effective_slots,
+                                   expected_accepted_tokens,
                                    mean_pages_held, mixed_iteration_flops,
-                                   tp_shards_kv, tp_shards_weights)
+                                   streamed_params, tp_shards_kv,
+                                   tp_shards_weights)
 from repro.core.hardware import HardwareSpec
 from repro.core.model_config import ModelSpec
 from repro.core.precision import PrecisionSpec
@@ -113,7 +115,10 @@ def mixed_iteration_cost(spec: ModelSpec, hw: HardwareSpec,
     batching throughput from the same roofline terms as ``breakdown()``.
 
     Memory term: weights stream once per iteration (shared by every slot
-    in the batch — the whole point of iteration-level batching) plus the
+    in the batch — the whole point of iteration-level batching; of a
+    routed layer's held experts only those the iteration's rows are
+    expected to hit, ``analytical.streamed_params``) plus the per-slot
+    conv state read and written, plus the
     paged KV actually touched: ``avg_context`` tokens per live decode
     slot and the prefill tokens written once.  ``plan.bytes_per_token``
     carries the cache dtype (``plan_for_layout(..., cache_dtype=)``):
@@ -187,13 +192,16 @@ def mixed_iteration_cost(spec: ModelSpec, hw: HardwareSpec,
             f"plan already holds per-device bytes (built with tp="
             f"{plan.tp}); pass the global plan or drop the tp= argument "
             "— dividing twice would overstate throughput")
-    P = params if params is not None else blocks.param_count(spec, padded=False)
+    P = params if params is not None else streamed_params(
+        spec, prefill_tokens + decode_slots * spec_k)
     flops = mixed_iteration_flops(spec, prefill_tokens,
                                   decode_slots * spec_k,
                                   avg_context, cached_prefix_tokens)
     kv_global = plan.bytes_per_token * (
         decode_slots * (avg_context + spec_k - 1)
         + prefill_tokens + cached_prefix_tokens)
+    # conv layers read and write each decoding slot's state once
+    kv_global += 2 * conv_state_bytes(spec, decode_slots)
     kv_dev = kv_global / (tp if tp_shards_kv(spec, tp) else 1)
     weight_bytes = P * precision.bytes_per_param
     w_div = tp if tp_shards_weights(spec, tp) else 1

@@ -30,10 +30,32 @@ class MoESpec:
     # Experts are padded to a multiple of the EP axis so 60 experts shard
     # over 16 devices; dummy experts receive no router mass.
     pad_to_multiple: int = 1
+    # Routing: router scores are softmax or sigmoid of the router logits;
+    # ``expert_bias`` adds a learned per-expert bias to the scores for the
+    # top-k SELECTION only (the weights stay the unbiased scores);
+    # ``norm_topk`` divides the k weights by their sum; ``scale``
+    # multiplies them (routed_scaling_factor).
+    score: str = "softmax"         # softmax | sigmoid
+    expert_bias: bool = False
+    norm_topk: bool = True
+    scale: float = 1.0
+    # Experts [first, end) this chip holds (expert parallelism): the
+    # router keeps all ``num_experts`` outputs and the layer computes
+    # only the held experts' part.  None holds every expert.
+    held: Optional[Tuple[int, int]] = None
 
     @property
     def padded_experts(self) -> int:
         return _round_up(self.num_experts, self.pad_to_multiple)
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held if self.held is not None else (0, self.padded_experts)
+
+    @property
+    def num_held(self) -> int:
+        lo, hi = self.held_range
+        return hi - lo
 
 
 @dataclass(frozen=True)
@@ -79,6 +101,8 @@ class ModelSpec:
 
     # Norm / misc
     norm: str = "rmsnorm"          # rmsnorm | layernorm
+    norm_eps: float = 1e-6         # RMSNorm epsilon
+    qk_norm: bool = False          # per-head RMSNorm on q and k before RoPE
     act: str = "silu"              # silu | gelu
     tie_embeddings: bool = False
 
@@ -89,6 +113,15 @@ class ModelSpec:
     attn_every: int = 0            # hybrid (zamba2): shared attn block every k SSM layers
     shared_attn_block: bool = False  # zamba2: the interleaved attn block reuses ONE set of weights
     xlstm: Optional[XLSTMSpec] = None
+    # Explicit per-layer mixer kinds ("attn" | "conv") for stacks whose
+    # pattern is not periodic (LFM2); empty derives the kinds below.
+    layer_types: Tuple[str, ...] = ()
+    # Leading layers with a dense FFN before the MoE layers begin.
+    dense_layers: int = 0
+    # Gated short convolution ("conv" layers): causal depthwise kernel
+    # length (conv_L_cache); decoding carries its last conv_width - 1
+    # inputs per slot.
+    conv_width: int = 0
 
     # Encoder-decoder (whisper)
     encoder_layers: int = 0
@@ -124,7 +157,9 @@ class ModelSpec:
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Per-layer block kind, in order ("attn", "attn_global", "attn_local",
-        "ssm", "mlstm", "slstm")."""
+        "conv", "ssm", "mlstm", "slstm")."""
+        if self.layer_types:
+            return tuple(self.layer_types)
         kinds = []
         for i in range(self.num_layers):
             if self.xlstm is not None:
@@ -140,6 +175,14 @@ class ModelSpec:
                 k = "attn"
             kinds.append(k)
         return tuple(kinds)
+
+    def moe_layer(self, i: int) -> bool:
+        """Whether layer ``i``'s FFN is the routed-expert block."""
+        return (self.moe is not None and i >= self.dense_layers
+                and i % self.moe_every == 0)
+
+    def num_conv_layers(self) -> int:
+        return sum(1 for k in self.layer_kinds() if k == "conv")
 
     def num_attention_layers(self) -> int:
         """Layers that carry a KV cache (incl. zamba2's shared-block applications)."""
@@ -174,7 +217,20 @@ class ModelSpec:
                 expert_ff=width * 2,
                 num_shared_experts=min(self.moe.num_shared_experts, 1),
                 shared_ff=width * 2 if self.moe.num_shared_experts else 0,
-                pad_to_multiple=1)
+                pad_to_multiple=1,
+                held=(0, 2) if self.moe.held is not None else None)
+        if self.layer_types:
+            # keep every kind: the published pattern cut to ``layers``,
+            # with at least one conv and one attention layer
+            types = [self.layer_types[i % len(self.layer_types)]
+                     for i in range(layers)]
+            if "attn" not in types:
+                types[-1] = "attn"
+            if "conv" not in types:
+                types[0] = "conv"
+            kw["layer_types"] = tuple(types)
+        if self.dense_layers:
+            kw["dense_layers"] = min(self.dense_layers, 1)
         if self.ssm is not None:
             kw["ssm"] = dataclasses.replace(self.ssm, state_dim=16, head_dim=16, chunk=32)
         if self.xlstm is not None:

@@ -93,12 +93,9 @@ def per_operator_flops(spec: ModelSpec, s_ctx: int) -> Dict[str, float]:
     }
     mlp = 0.0
     for i, k in enumerate(spec.layer_kinds()):
-        if not k.startswith("attn"):
+        if not k.startswith("attn") and k != "conv":
             continue
-        if spec.moe is not None and i % spec.moe_every == 0:
-            mlp += blocks.moe_flops_per_token(spec)
-        else:
-            mlp += blocks.mlp_flops_per_token(spec)
+        mlp += blocks.ffn_flops_per_token(spec, i)
     out["mlp"] = mlp
     return out
 

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.model_config import ModelSpec
-from repro.quant.qlinear import qdot
+from repro.quant.qlinear import dequant_param, qdot
 from repro.models.scan_util import scan as _scan
 
 Params = Dict[str, jnp.ndarray]
@@ -45,7 +45,17 @@ def layernorm(x: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
 def norm(spec: ModelSpec, p: Params, name: str, x: jnp.ndarray) -> jnp.ndarray:
     if spec.norm == "layernorm":
         return layernorm(x, p[name], p[name + "_b"])
-    return rmsnorm(x, p[name])
+    return rmsnorm(x, p[name], spec.norm_eps)
+
+
+def qk_norm(spec: ModelSpec, p: Params, q: jnp.ndarray, k: jnp.ndarray,
+            prefix: str = ""):
+    """Per-head RMSNorm of q (..., H, D) and k (..., KV, D) before RoPE,
+    where the spec asks for it (``qk_norm``)."""
+    if prefix + "q_norm" not in p:
+        return q, k
+    return (rmsnorm(q, p[prefix + "q_norm"], spec.norm_eps),
+            rmsnorm(k, p[prefix + "k_norm"], spec.norm_eps))
 
 
 def activation(spec: ModelSpec, x: jnp.ndarray) -> jnp.ndarray:
@@ -221,6 +231,7 @@ def attention_block(spec: ModelSpec, p: Params, x: jnp.ndarray,
     q = qdot(x, p[prefix + "wq"]).reshape(B, S, H, D)
     k = qdot(x, p[prefix + "wk"]).reshape(B, S, KV, D)
     v = qdot(x, p[prefix + "wv"]).reshape(B, S, KV, D)
+    q, k = qk_norm(spec, p, q, k, prefix)
     causal = kind != "enc_attn"
     if causal:
         q = rope(q, positions, spec.rope_theta)
@@ -270,25 +281,26 @@ def _gated_ff(spec: ModelSpec, wi, wo, x: jnp.ndarray) -> jnp.ndarray:
 
 def moe_block(spec: ModelSpec, p: Params, x: jnp.ndarray,
               group_size: int = 512) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """GShard-style capacity-based token-choice MoE (dense dispatch einsum).
+    """GShard-style capacity-based token-choice MoE (dense dispatch einsum),
+    the training path; serving runs the dropless ``routed_experts``.
 
-    Returns (output, aux_loss).  Expert weights carry a leading padded
-    expert dim sharded on the 'model' axis; the dispatch einsum becomes
-    the EP all-to-all under GSPMD.
+    Routing is ``route``'s; the held experts (``spec.moe.held_range``)
+    are the dispatch targets and assignments to other experts are
+    dropped.  Returns (output, aux_loss).  Expert weights carry a
+    leading padded expert dim sharded on the 'model' axis; the dispatch
+    einsum becomes the EP all-to-all under GSPMD.
     """
     m = spec.moe
     B, S, d = x.shape
-    E, Ep, k = m.num_experts, m.padded_experts, m.top_k
+    E, k = m.num_experts, m.top_k
+    lo, hi = m.held_range
+    Ep = hi - lo
     T = B * S
     g = min(group_size, T)
     G = T // g
     xg = x.reshape(G, g, d)
 
-    logits = jnp.einsum("gtd,de->gte", xg.astype(jnp.float32),
-                        p["router_w"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                       # (G,g,E)
-    top_p, top_e = jax.lax.top_k(probs, k)                        # (G,g,k)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    probs, top_e, top_p = route(spec, p, xg)                      # (G,g,E|k)
 
     # Switch aux loss: E * sum_e f_e * P_e
     fe = jnp.mean(jax.nn.one_hot(top_e[..., 0], E), axis=(0, 1))
@@ -296,7 +308,8 @@ def moe_block(spec: ModelSpec, p: Params, x: jnp.ndarray,
     aux = E * jnp.sum(fe * pe)
 
     cap = max(1, int(g * k * m.capacity_factor / E))
-    onehot = jax.nn.one_hot(top_e, Ep, dtype=jnp.float32)         # (G,g,k,Ep)
+    # ids outside the held range one-hot to zero rows: never dispatched
+    onehot = jax.nn.one_hot(top_e - lo, Ep, dtype=jnp.float32)    # (G,g,k,Ep)
     # global position-in-expert over the flattened (token, k) sequence so
     # different k-lanes of different tokens never collide on a slot
     flat = onehot.reshape(G, g * k, Ep)
@@ -311,7 +324,6 @@ def moe_block(spec: ModelSpec, p: Params, x: jnp.ndarray,
 
     xin = jnp.einsum("gtec,gtd->egcd", dispatch,
                      xg.astype(jnp.float32)).astype(x.dtype)      # (Ep,G,cap,d)
-    from repro.quant.qlinear import dequant_param
     wi = dequant_param(p["experts_wi"])
     wo = dequant_param(p["experts_wo"])
     h = jnp.einsum("egcd,edf->egcf", xin, wi.astype(x.dtype))
@@ -324,3 +336,136 @@ def moe_block(spec: ModelSpec, p: Params, x: jnp.ndarray,
     if m.num_shared_experts:
         out = out + _gated_ff(spec, p["shared_wi"], p["shared_wo"], x)
     return out, aux.astype(jnp.float32)
+
+
+def route(spec: ModelSpec, p: Params, x: jnp.ndarray):
+    """Router of a routed-expert layer, in float32 over every published
+    expert: scores (T, E) (softmax or sigmoid of ``x @ router_w``), and
+    the top-k expert ids (T, k) and weights (T, k).  Selection ranks
+    ``scores + expert_bias`` where the spec has a selection bias; the
+    weights are the unbiased scores, divided by their sum (+1e-6 for
+    sigmoid scores, as the published sigmoid routers do) under
+    ``norm_topk``, times ``scale``."""
+    m = spec.moe
+    logits = jnp.dot(x.astype(jnp.float32), p["router_w"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if m.score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    if m.expert_bias:
+        _, top_e = jax.lax.top_k(scores + p["expert_bias"].astype(jnp.float32),
+                                 m.top_k)
+        top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+    else:
+        top_w, top_e = jax.lax.top_k(scores, m.top_k)
+    if m.norm_topk:
+        eps = 1e-6 if m.score == "sigmoid" else 0.0
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + eps)
+    return scores, top_e, top_w * m.scale
+
+
+def routed_experts(spec: ModelSpec, p: Params, x: jnp.ndarray,
+                   valid: Optional[jnp.ndarray] = None
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Dropless routed-expert layer over the experts this chip holds.
+
+    Routes every row over all published experts (``route``), keeps the
+    (row, expert) assignments that fall in the held range
+    ``spec.moe.held_range``, groups them by held expert and runs the
+    gated FFN as two grouped matmuls (``jax.lax.ragged_dot``: on a TPU
+    the ``ragged-dot`` ops of a device trace) over the held experts'
+    weights, then combines each row's weighted expert outputs.  No row
+    is ever dropped, so a row's output never depends on the other rows
+    of its batch.  Assignments to experts held elsewhere contribute
+    nothing here; rows where ``valid`` (T,) is False (bucket padding)
+    are routed nowhere.  Shared experts run densely on every row.
+
+    x: (..., d).  Returns (output like x, rows per held expert (H,)
+    int32)."""
+    m = spec.moe
+    lo, hi = m.held_range
+    H, k = hi - lo, m.top_k
+    shape, d = x.shape, x.shape[-1]
+    xf = x.reshape(-1, d)
+    T = xf.shape[0]
+    _, top_e, top_w = route(spec, p, xf)
+    local = top_e - lo
+    held = (local >= 0) & (local < H)
+    if valid is not None:
+        held &= valid.reshape(T, 1)
+    # sort the T*k assignments by held expert; the rest go last, in a
+    # group of their own that no weight reads
+    key = jnp.where(held, local, H).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.bincount(key, length=H + 1).astype(jnp.int32)
+    rows = xf[order // k]
+    wi = dequant_param(p["experts_wi"]).astype(x.dtype)
+    wo = dequant_param(p["experts_wo"]).astype(x.dtype)
+    h = jax.lax.ragged_dot(rows, wi, counts[:H],
+                           preferred_element_type=jnp.float32)
+    gate, up = jnp.split(h, 2, axis=-1)
+    h = (activation(spec, gate) * up).astype(x.dtype)
+    y = jax.lax.ragged_dot(h, wo, counts[:H],
+                           preferred_element_type=jnp.float32)
+    # rows past the held groups are never written by the grouped
+    # matmul: select them away rather than multiply (no NaN leaks)
+    y = jnp.where((key[order] < H)[:, None], y, 0.0)
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
+    y = y[inv].reshape(T, k, d)
+    out = jnp.sum(y * top_w[..., None], axis=1)
+    out = out.reshape(shape).astype(x.dtype)
+    if m.num_shared_experts:
+        out = out + _gated_ff(spec, p["shared_wi"], p["shared_wo"], x)
+    return out, counts[:H]
+
+
+def ffn(spec: ModelSpec, p: Params, x: jnp.ndarray,
+        valid: Optional[jnp.ndarray] = None
+        ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
+    """A layer's serving FFN: the dropless routed-expert layer where the
+    layer has a router, else the dense MLP.  Returns (output, rows per
+    held expert or None)."""
+    if "router_w" in p:
+        return routed_experts(spec, p, x, valid)
+    return mlp_block(spec, p, x), None
+
+
+# ---------------------------------------------------------------------------
+# Gated short convolution
+# ---------------------------------------------------------------------------
+
+def _conv_in(p: Params, x: jnp.ndarray):
+    """[B, C, h] = split3(x W_in); returns (B * h, C)."""
+    bch = qdot(x, p["conv_in"])
+    b, c, h = jnp.split(bch, 3, axis=-1)
+    return b * h, c
+
+
+def short_conv(spec: ModelSpec, p: Params, x: jnp.ndarray,
+               state: Optional[jnp.ndarray] = None,
+               true_len=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Gated short convolution over a sequence x (B, S, d):
+    ``y = C * conv(B * h)``, ``out = y W_out``, with ``conv`` causal and
+    depthwise over ``conv_width`` taps (``conv_w[j]`` weights the input
+    ``conv_width - 1 - j`` positions back).
+
+    ``state`` (B, conv_width - 1, d) holds the ``B * h`` rows before the
+    sequence (zeros at a sequence's start).  Returns (out, new state):
+    the last ``conv_width - 1`` rows of ``B * h`` before position
+    ``true_len`` (default S), so a bucket-padded prompt leaves the state
+    at its true end."""
+    K = spec.conv_width
+    Bn, S, d = x.shape
+    bx, c = _conv_in(p, x)
+    if state is None:
+        state = jnp.zeros((Bn, K - 1, d), jnp.float32)
+    full = jnp.concatenate([state.astype(bx.dtype), bx], axis=1)  # (B, S+K-1, d)
+    w = p["conv_w"].astype(jnp.float32)
+    conv = sum(full[:, j:j + S].astype(jnp.float32) * w[j]
+               for j in range(K))
+    y = (c.astype(jnp.float32) * conv).astype(x.dtype)
+    end = S if true_len is None else true_len
+    new_state = jax.lax.dynamic_slice_in_dim(full, end, K - 1, axis=1)
+    return qdot(y, p["conv_out"]), new_state.astype(jnp.float32)
+

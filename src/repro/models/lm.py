@@ -39,12 +39,14 @@ Params = Dict[str, Any]
 
 @dataclasses.dataclass(frozen=True)
 class Group:
-    kind: str          # attn | attn_local | attn_global | ssm | ssm_shared | mlstm | slstm
+    kind: str          # attn | attn_local | attn_global | conv | ssm | ssm_shared | mlstm | slstm
     base: int          # first layer index
     n: int             # number of layers
 
 
 def group_plan(spec: ModelSpec) -> List[Group]:
+    """Runs of consecutive layers with one parameter layout: the same
+    kind and the same FFN (dense or routed experts)."""
     kinds = list(spec.layer_kinds())
     if spec.ssm is not None and spec.attn_every:
         kinds = ["ssm_shared" if (i + 1) % spec.attn_every == 0 else k
@@ -53,7 +55,8 @@ def group_plan(spec: ModelSpec) -> List[Group]:
     i = 0
     while i < len(kinds):
         j = i
-        while j < len(kinds) and kinds[j] == kinds[i]:
+        while (j < len(kinds) and kinds[j] == kinds[i]
+               and spec.moe_layer(j) == spec.moe_layer(i)):
             j += 1
         groups.append(Group(kinds[i], i, j - i))
         i = j
@@ -141,14 +144,13 @@ def _layer_forward(spec: ModelSpec, kind: str, p: Params, x, positions,
             ("wq", "wk", "wv", "wo", "mlp", "experts", "shared", "cross"))
             else v for k, v in p.items()}
     base = _base_kind(kind)
-    if base in ("attn", "attn_local", "attn_global", "enc_attn"):
-        h = L.attention_block(spec, p, L.norm(spec, p, "norm1", x), positions,
-                              kind=base, impl=impl)
-        x = x + h
-        if spec.cross_attention and base != "enc_attn":
-            h = L.cross_attention_block(spec, p, L.norm(spec, p, "norm_cross", x),
-                                        enc_out)
+    if base in ("attn", "attn_local", "attn_global", "enc_attn", "conv"):
+        if base == "conv":
+            h, _ = L.short_conv(spec, p, L.norm(spec, p, "norm1", x))
             x = x + h
+        else:
+            x = _attn_residual(spec, base, p, x, L.norm(spec, p, "norm1", x),
+                               positions, enc_out, impl)
         y = L.norm(spec, p, "norm2", x)
         if "router_w" in p:
             h, aux = L.moe_block(spec, p, y)
@@ -166,6 +168,28 @@ def _layer_forward(spec: ModelSpec, kind: str, p: Params, x, positions,
     else:
         raise ValueError(kind)
     return x, aux
+
+
+def _attn_residual(spec: ModelSpec, base: str, p: Params, x, xn, positions,
+                   enc_out, impl: str):
+    """x plus the attention of its normed ``xn`` (and cross-attention
+    where the spec has it)."""
+    x = x + L.attention_block(spec, p, xn, positions, kind=base, impl=impl)
+    if spec.cross_attention and base != "enc_attn":
+        x = x + L.cross_attention_block(
+            spec, p, L.norm(spec, p, "norm_cross", x), enc_out)
+    return x
+
+
+def _ffn_residual(spec: ModelSpec, p: Params, y, valid=None,
+                  expert_rows: Optional[list] = None):
+    """y plus the serving FFN of its normed self (``layers.ffn``: the
+    dropless routed-expert layer or the dense MLP).  A routed layer's
+    rows per held expert are appended to ``expert_rows`` when given."""
+    h, rows = L.ffn(spec, p, L.norm(spec, p, "norm2", y), valid)
+    if rows is not None and expert_rows is not None:
+        expert_rows.append(rows)
+    return y + h
 
 
 def _shared_block_forward(spec: ModelSpec, sp: Params, x, positions, impl):
@@ -285,8 +309,9 @@ def _norm_cache_dtype(dtype) -> str:
 def paged_page_size(cache) -> int:
     """Token capacity of one page — from the scale pool for quantized
     caches (the int4 value pool's token dim is nibble-packed to half).
-    Scale pools are LANE-MAJOR (P, KV, page): token dim last."""
-    entry = cache["groups"][0][0]
+    Scale pools are LANE-MAJOR (P, KV, page): token dim last.  Conv
+    layers' entries hold no pages."""
+    entry = next(e for g in cache["groups"] for e in g if "k_pages" in e)
     if "k_scale" in entry:
         return entry["k_scale"].shape[-1]
     return entry["k_pages"].shape[1]
@@ -306,9 +331,17 @@ def init_paged_cache(spec: ModelSpec, batch: int, max_seq: int,
                      layout: PagedLayout, dtype=jnp.float32) -> Params:
     """Paged serve cache: per-layer page pools + per-slot block tables.
 
-    Supported for attention-only stacks (attn / attn_local /
-    attn_global); recurrent state (ssm/xlstm) and cross-attention have
-    no paged representation yet.  ``dtype`` is a jnp dtype or one of
+    Supported for attention stacks (attn / attn_local / attn_global)
+    and for gated short-convolution layers ("conv"): those hold no
+    pages (an empty entry in ``groups``) but a per-slot state,
+    ``cache["conv"]``, one (batch, conv_width - 1, d) float32 array per
+    conv layer in layer order — the last ``conv_width - 1`` conv inputs
+    of each slot, written by the slot's own prefill and rolled by
+    decode.  Specs with routed experts also carry ``expert_rows``
+    (MoE layers, held experts) int32: the serve backend writes each
+    call's rows per held expert there.  Other recurrent state
+    (ssm/xlstm) and cross-attention have no paged representation yet.
+    ``dtype`` is a jnp dtype or one of
     "fp32" | "int8" | "int4": quantized caches store int8 pools with
     per-token-per-head f32 scales (``k_scale``/``v_scale``); "int4"
     nibble-packs two adjacent tokens per byte along the pool's token
@@ -320,7 +353,8 @@ def init_paged_cache(spec: ModelSpec, batch: int, max_seq: int,
     vector, not a scalar.
     """
     for kind in spec.layer_kinds():
-        if _base_kind(kind) not in ("attn", "attn_local", "attn_global"):
+        if _base_kind(kind) not in ("attn", "attn_local", "attn_global",
+                                    "conv"):
             raise NotImplementedError(
                 f"paged cache: unsupported layer kind {kind!r}")
     if spec.cross_attention or spec.encoder_layers:
@@ -343,6 +377,9 @@ def init_paged_cache(spec: ModelSpec, batch: int, max_seq: int,
         pool_dtype = jnp.int8
     pool = (layout.num_pages, tok, KV, D)
     for g in group_plan(spec):
+        if g.kind == "conv":
+            cache["groups"].append([{} for _ in range(g.n)])
+            continue
         layers = []
         for _ in range(g.n):
             entry: Dict[str, jnp.ndarray] = {
@@ -355,6 +392,14 @@ def init_paged_cache(spec: ModelSpec, batch: int, max_seq: int,
                 entry["v_scale"] = jnp.zeros(sshape, jnp.float32)
             layers.append(entry)
         cache["groups"].append(layers)
+    if spec.num_conv_layers():
+        cache["conv"] = [
+            jnp.zeros((batch, spec.conv_width - 1, spec.d_model), jnp.float32)
+            for _ in range(spec.num_conv_layers())]
+    if spec.moe is not None:
+        n_moe = sum(spec.moe_layer(i) for i in range(spec.num_layers))
+        cache["expert_rows"] = jnp.zeros((n_moe, spec.moe.num_held),
+                                         jnp.int32)
     return cache
 
 
@@ -383,7 +428,8 @@ def init_cache(spec: ModelSpec, batch: int, max_seq: int,
         for _ in range(g.n):
             entry: Dict[str, jnp.ndarray] = {}
             for name, shape in shapes.items():
-                dt = jnp.float32 if base in ("ssm", "mlstm", "slstm") else dtype
+                dt = (jnp.float32 if base in ("ssm", "mlstm", "slstm", "conv")
+                      else dtype)
                 fill = -jnp.inf if name in ("m", "m_") else 0.0
                 entry[name] = jnp.full(shape, fill, dt)
             if g.kind == "ssm_shared":
@@ -404,6 +450,8 @@ def _attn_prefill_kv(spec, p, xn, positions):
     KV, D = spec.num_kv_heads, spec.head_dim
     k = qdot(xn, p["wk"]).reshape(B, S, KV, D)
     v = qdot(xn, p["wv"]).reshape(B, S, KV, D)
+    if "k_norm" in p:
+        k = L.rmsnorm(k, p["k_norm"], spec.norm_eps)
     k = L.rope(k, positions, spec.rope_theta)
     return k, v
 
@@ -413,8 +461,8 @@ def _attn_prefill_kv(spec, p, xn, positions):
 # ---------------------------------------------------------------------------
 
 def prefill(params, spec: ModelSpec, batch, *, max_seq: Optional[int] = None,
-            impl: str = "auto", cache_dtype=None,
-            true_len=None) -> Tuple[jnp.ndarray, Params]:
+            impl: str = "auto", cache_dtype=None, true_len=None,
+            expert_rows: Optional[list] = None) -> Tuple[jnp.ndarray, Params]:
     """Run the prompt, return (last-position logits, filled cache).
 
     ``true_len`` (traced scalar) supports bucket-padded prompts: tokens
@@ -422,13 +470,18 @@ def prefill(params, spec: ModelSpec, batch, *, max_seq: Optional[int] = None,
     from influencing earlier positions, the returned logits come from
     position ``true_len - 1``, and ``cache["pos"]`` is set to
     ``true_len`` so decode overwrites the padding k/v.  One XLA compile
-    per bucket length instead of one per prompt length.
+    per bucket length instead of one per prompt length.  Conv layers
+    leave their state at ``true_len``, and routed-expert layers route
+    padding rows nowhere; their rows per held expert are appended to
+    ``expert_rows`` when given.
     """
     x = _embed_inputs(params, spec, batch)
     B, S = x.shape[:2]
     max_seq = max_seq or S
     dtype = cache_dtype or x.dtype
     positions = jnp.arange(S)[None]
+    valid = (None if true_len is None else
+             jnp.broadcast_to(positions < true_len, (B, S)).reshape(-1))
     enc_out = None
     if spec.encoder_layers:
         enc_out = _encoder_forward(params, spec, batch["frames"], impl, False)
@@ -444,10 +497,12 @@ def prefill(params, spec: ModelSpec, batch, *, max_seq: Optional[int] = None,
             y0 = carry
             xn = L.norm(spec, pslice, "norm1", y0)
             out: Dict[str, jnp.ndarray] = {}
+            rows: list = []
             if _base.startswith("attn"):
                 k, v = _attn_prefill_kv(spec, pslice, xn, positions)
-                y, _ = _layer_forward(spec, _kind, pslice, y0, positions,
-                                      enc_out, shared_p, impl, None)
+                y = _attn_residual(spec, _base, pslice, y0, xn, positions,
+                                   enc_out, impl)
+                y = _ffn_residual(spec, pslice, y, valid, rows)
                 if _base == "attn_local" and spec.sliding_window and \
                         max_seq == spec.sliding_window:
                     # ring layout: slot j holds the unique p ≡ j (mod W)
@@ -471,6 +526,10 @@ def prefill(params, spec: ModelSpec, batch, *, max_seq: Optional[int] = None,
                         B, Se, KV, D)
                     out["cross_v"] = qdot(enc_out, pslice["cross_wv"]).reshape(
                         B, Se, KV, D)
+            elif _base == "conv":
+                h, out["conv_state"] = L.short_conv(spec, pslice, xn,
+                                                    true_len=true_len)
+                y = _ffn_residual(spec, pslice, y0 + h, valid, rows)
             elif _base == "ssm":
                 y, st = _mamba_prefill(spec, pslice, y0)
                 out.update(st)
@@ -487,9 +546,11 @@ def prefill(params, spec: ModelSpec, batch, *, max_seq: Optional[int] = None,
             else:                                   # slstm
                 y, st = _slstm_prefill(spec, pslice, y0)
                 out.update(st)
-            return y, out
+            return y, (out, rows)
 
-        x, outs = _scan(body, x, gp)
+        x, (outs, rows) = _scan(body, x, gp)
+        if expert_rows is not None:
+            expert_rows.extend(r[li] for r in rows for li in range(g.n))
         for li in range(len(cache["groups"][gi])):
             entry = cache["groups"][gi][li]
             for k_, v_ in outs.items():
@@ -536,6 +597,7 @@ def _attn_decode(spec, p, x, pos, kv, *, kind, prefix="") -> Tuple[jnp.ndarray, 
     q = qdot(x, p[prefix + "wq"]).reshape(B, 1, H, D)
     k = qdot(x, p[prefix + "wk"]).reshape(B, 1, KV, D)
     v = qdot(x, p[prefix + "wv"]).reshape(B, 1, KV, D)
+    q, k = L.qk_norm(spec, p, q, k, prefix)
     posb = jnp.full((B, 1), pos, jnp.int32)
     q = L.rope(q, posb, spec.rope_theta)
     k = L.rope(k, posb, spec.rope_theta)
@@ -627,6 +689,7 @@ def _attn_decode_paged(spec, p, x, pos, kv, block_tables, *,
     q = qdot(x, p["wq"]).reshape(B, 1, H, D)
     k = qdot(x, p["wk"]).reshape(B, 1, KV, D)
     v = qdot(x, p["wv"]).reshape(B, 1, KV, D)
+    q, k = L.qk_norm(spec, p, q, k)
     posb = pos[:, None]
     q = L.rope(q, posb, spec.rope_theta)
     k = L.rope(k, posb, spec.rope_theta)
@@ -689,6 +752,7 @@ def _attn_decode_window_paged(spec, p, x, pos, lens, kv, block_tables, *,
     q = qdot(x, p["wq"]).reshape(B, K, H, D)
     k = qdot(x, p["wk"]).reshape(B, K, KV, D)
     v = qdot(x, p["wv"]).reshape(B, K, KV, D)
+    q, k = L.qk_norm(spec, p, q, k)
     posb = pos[:, None] + jnp.arange(K)[None]            # (B, K) absolute
     q = L.rope(q, posb, spec.rope_theta)
     k = L.rope(k, posb, spec.rope_theta)
@@ -762,6 +826,7 @@ def _suffix_attn_paged(spec, p, xn, positions, kv, pref_pages, prefix_len,
     q = qdot(xn, p["wq"]).reshape(B, S, H, D)
     k = qdot(xn, p["wk"]).reshape(B, S, KV, D)
     v = qdot(xn, p["wv"]).reshape(B, S, KV, D)
+    q, k = L.qk_norm(spec, p, q, k)
     q = L.rope(q, positions, spec.rope_theta)
     k = L.rope(k, positions, spec.rope_theta)
 
@@ -818,7 +883,9 @@ def _suffix_attn_paged(spec, p, xn, positions, kv, pref_pages, prefix_len,
 
 def prefill_paged(params, spec: ModelSpec, tokens, cache, slot, bt_row,
                   prefix_len, true_len, *, n_prefix_pages: int,
-                  ring=False, mesh=None) -> Tuple[jnp.ndarray, Params]:
+                  ring=False, mesh=None,
+                  expert_rows: Optional[list] = None
+                  ) -> Tuple[jnp.ndarray, Params]:
     """Prefill a prompt SUFFIX directly into a paged cache slot whose
     first ``prefix_len`` tokens are already cached (prefix-cache hit).
 
@@ -841,6 +908,14 @@ def prefill_paged(params, spec: ModelSpec, tokens, cache, slot, bt_row,
     The prefix gather follows the ring position mapping (see
     ``_suffix_attn_paged``), so chunked prefill and swap rejoins compose
     with windowed slots unchanged.
+
+    Conv layers start from the slot's state in ``cache["conv"]`` when
+    ``prefix_len`` > 0 (the slot's earlier chunks wrote it) and from
+    zeros otherwise, and leave it at the suffix's true end.  A prefix
+    that came from anywhere else (a prefix-cache hit, a swap-in) has no
+    state here: the engine turns those paths off for such specs.
+    Routed-expert layers route padding rows nowhere and append their
+    rows per held expert to ``expert_rows`` when given.
     """
     page = paged_page_size(cache)
     S = tokens.shape[1]
@@ -858,6 +933,9 @@ def prefill_paged(params, spec: ModelSpec, tokens, cache, slot, bt_row,
         tgt_page = jnp.where(jnp.arange(S) < true_len, bt_row[page_idx], 0)
     tgt_off = abs_pos % page
 
+    valid = jnp.arange(S) < true_len
+    conv = list(cache.get("conv", ()))
+    ci = 0
     x = jnp.take(params["global"]["embed"], tokens, axis=0)
     if spec.name.startswith("gemma"):
         x = x * math.sqrt(spec.d_model)
@@ -868,16 +946,19 @@ def prefill_paged(params, spec: ModelSpec, tokens, cache, slot, bt_row,
         for li, cslice in enumerate(cg):
             pslice = jax.tree_util.tree_map(lambda v: v[li], gp)
             xn = L.norm(spec, pslice, "norm1", x)
-            h, kv_new = _suffix_attn_paged(
-                spec, pslice, xn, positions, cslice, pref_pages, prefix_len,
-                tgt_page, tgt_off, kind=base, ring=ring, mesh=mesh)
-            y = x + h
-            y2 = L.norm(spec, pslice, "norm2", y)
-            if "router_w" in pslice:
-                h2, _ = L.moe_block(spec, pslice, y2)
+            if base == "conv":
+                start = jnp.where(prefix_len > 0, conv[ci][slot], 0.0)
+                h, st = L.short_conv(spec, pslice, xn, start[None],
+                                     true_len=true_len)
+                conv[ci] = conv[ci].at[slot].set(st[0])
+                ci += 1
+                kv_new = cslice
             else:
-                h2 = L.mlp_block(spec, pslice, y2)
-            x = y + h2
+                h, kv_new = _suffix_attn_paged(
+                    spec, pslice, xn, positions, cslice, pref_pages,
+                    prefix_len, tgt_page, tgt_off, kind=base, ring=ring,
+                    mesh=mesh)
+            x = _ffn_residual(spec, pslice, x + h, valid, expert_rows)
             new_layers.append(kv_new)
         new_groups.append(new_layers)
 
@@ -885,17 +966,21 @@ def prefill_paged(params, spec: ModelSpec, tokens, cache, slot, bt_row,
         x, jnp.asarray(true_len, jnp.int32) - 1, 1, axis=1)
     logits = _lm_head(params, spec, x_last)
     new_cache = {
+        **cache,
         "pos": cache["pos"].at[slot].set(
             jnp.asarray(prefix_len + true_len, jnp.int32)),
         "block_tables": cache["block_tables"].at[slot].set(bt_row),
         "groups": new_groups,
     }
+    if conv:
+        new_cache["conv"] = conv
     return logits, new_cache
 
 
 def decode_step_paged(params, spec: ModelSpec, cache, tokens, *,
-                      ring=False, mesh=None,
-                      shard_params=False) -> Tuple[jnp.ndarray, Params]:
+                      ring=False, mesh=None, shard_params=False,
+                      valid=None, expert_rows: Optional[list] = None
+                      ) -> Tuple[jnp.ndarray, Params]:
     """One decode step over a PAGED cache (per-slot positions).
 
     Same layer unroll as ``decode_step`` but attention reads/writes go
@@ -907,10 +992,15 @@ def decode_step_paged(params, spec: ModelSpec, cache, tokens, *,
     declares that the weights themselves are column/row-parallel so the
     attention output stays head-sharded into row-parallel wo (GSPMD
     partitions the MLP / embed / lm-head matmuls from the committed
-    param shardings on its own).
+    param shardings on its own).  Conv layers roll every slot's state in
+    ``cache["conv"]`` by the new token; routed-expert layers route
+    slots where ``valid`` (B,) is False (idle) nowhere, and append
+    their rows per held expert to ``expert_rows`` when given.
     """
     pos = cache["pos"]
     bt = cache["block_tables"]
+    conv = list(cache.get("conv", ()))
+    ci = 0
     x = jnp.take(params["global"]["embed"], tokens, axis=0)
     if spec.name.startswith("gemma"):
         x = x * math.sqrt(spec.d_model)
@@ -921,27 +1011,29 @@ def decode_step_paged(params, spec: ModelSpec, cache, tokens, *,
         for li, cslice in enumerate(cg):
             pslice = jax.tree_util.tree_map(lambda v: v[li], gp)
             xn = L.norm(spec, pslice, "norm1", x)
-            h, kv_new = _attn_decode_paged(spec, pslice, xn, pos, cslice,
-                                           bt, kind=base, ring=ring,
-                                           mesh=mesh,
-                                           shard_params=shard_params)
-            y = x + h
-            y2 = L.norm(spec, pslice, "norm2", y)
-            if "router_w" in pslice:
-                h2, _ = L.moe_block(spec, pslice, y2, group_size=y2.shape[0])
+            if base == "conv":
+                h, conv[ci] = L.short_conv(spec, pslice, xn, conv[ci])
+                ci += 1
+                kv_new = cslice
             else:
-                h2 = L.mlp_block(spec, pslice, y2)
-            x = y + h2
+                h, kv_new = _attn_decode_paged(spec, pslice, xn, pos, cslice,
+                                               bt, kind=base, ring=ring,
+                                               mesh=mesh,
+                                               shard_params=shard_params)
+            x = _ffn_residual(spec, pslice, x + h, valid, expert_rows)
             new_layers.append(kv_new)
         new_groups.append(new_layers)
     logits = _lm_head(params, spec, x)
-    new_cache = {"pos": pos + 1, "block_tables": bt, "groups": new_groups}
+    new_cache = {**cache, "pos": pos + 1, "groups": new_groups}
+    if conv:
+        new_cache["conv"] = conv
     return logits, new_cache
 
 
 def decode_window_paged(params, spec: ModelSpec, cache, tokens, lens, *,
-                        ring=False, mesh=None,
-                        shard_params=False) -> Tuple[jnp.ndarray, Params]:
+                        ring=False, mesh=None, shard_params=False,
+                        expert_rows: Optional[list] = None
+                        ) -> Tuple[jnp.ndarray, Params]:
     """K-token decode window over a paged cache (speculative verify).
 
     ``tokens`` is (B, K): the last committed token followed by K-1
@@ -956,9 +1048,15 @@ def decode_window_paged(params, spec: ModelSpec, cache, tokens, lens, *,
     valid context; those rows are overwritten before they can ever be
     read).  K=1 with ``lens=1`` degenerates to ``decode_step_paged``
     minus the pos advance — the serve backend keeps K=1 on the original
-    path so the non-speculative program is byte-identical.
+    path so the non-speculative program is byte-identical.  Conv layers
+    are refused: rejected drafts would leave their rows in the state,
+    which keeps no history to roll back.
     """
+    if spec.num_conv_layers():
+        raise ValueError(f"{spec.name}: a verify window cannot roll back "
+                         "per-slot conv state")
     pos = cache["pos"]
+    valid = (jnp.arange(tokens.shape[1])[None] < lens[:, None]).reshape(-1)
     bt = cache["block_tables"]
     x = jnp.take(params["global"]["embed"], tokens, axis=0)
     if spec.name.startswith("gemma"):
@@ -973,23 +1071,18 @@ def decode_window_paged(params, spec: ModelSpec, cache, tokens, lens, *,
             h, kv_new = _attn_decode_window_paged(
                 spec, pslice, xn, pos, lens, cslice, bt, kind=base,
                 ring=ring, mesh=mesh, shard_params=shard_params)
-            y = x + h
-            y2 = L.norm(spec, pslice, "norm2", y)
-            if "router_w" in pslice:
-                h2, _ = L.moe_block(spec, pslice, y2, group_size=y2.shape[0])
-            else:
-                h2 = L.mlp_block(spec, pslice, y2)
-            x = y + h2
+            x = _ffn_residual(spec, pslice, x + h, valid, expert_rows)
             new_layers.append(kv_new)
         new_groups.append(new_layers)
     logits = _lm_head(params, spec, x)
-    new_cache = {"pos": pos, "block_tables": bt, "groups": new_groups}
+    new_cache = {**cache, "groups": new_groups}
     return logits, new_cache
 
 
 def decode_step(params, spec: ModelSpec, cache, tokens, *,
-                ring=False, mesh=None,
-                shard_params=False) -> Tuple[jnp.ndarray, Params]:
+                ring=False, mesh=None, shard_params=False,
+                valid=None, expert_rows: Optional[list] = None
+                ) -> Tuple[jnp.ndarray, Params]:
     """One decoding step for the whole batch. tokens: (B, 1) int32.
 
     Decode unrolls a python loop over layers with PER-LAYER cache buffers:
@@ -999,11 +1092,14 @@ def decode_step(params, spec: ModelSpec, cache, tokens, *,
     bodies are small, so the unrolled compile stays cheap.
 
     A paged cache (built with ``init_cache(..., paged=...)``) dispatches
-    to ``decode_step_paged``.
+    to ``decode_step_paged``.  Routed-expert layers route rows where
+    ``valid`` (B,) is False nowhere, and append their rows per held
+    expert to ``expert_rows`` when given.
     """
     if "block_tables" in cache:
         return decode_step_paged(params, spec, cache, tokens, ring=ring,
-                                 mesh=mesh, shard_params=shard_params)
+                                 mesh=mesh, shard_params=shard_params,
+                                 valid=valid, expert_rows=expert_rows)
     if ring:
         raise ValueError("ring layout requires a paged cache")
     pos = cache["pos"]
@@ -1031,12 +1127,11 @@ def decode_step(params, spec: ModelSpec, cache, tokens, *,
                                             cslice["cross_v"],
                                             cslice["cross_k"].shape[1] - 1)
                     y = y + qdot(oc.reshape(B, 1, H * D), pslice["cross_wo"])
-                y2 = L.norm(spec, pslice, "norm2", y)
-                if "router_w" in pslice:
-                    h2, _ = L.moe_block(spec, pslice, y2, group_size=y2.shape[0])
-                else:
-                    h2 = L.mlp_block(spec, pslice, y2)
-                y = y + h2
+                y = _ffn_residual(spec, pslice, y, valid, expert_rows)
+            elif _base == "conv":
+                h, new_c["conv_state"] = L.short_conv(
+                    spec, pslice, xn, cslice["conv_state"])
+                y = _ffn_residual(spec, pslice, y0 + h, valid, expert_rows)
             elif _base == "ssm":
                 h, st = R.mamba2_decode_step(
                     spec, pslice, xn,
@@ -1078,5 +1173,5 @@ def decode_step(params, spec: ModelSpec, cache, tokens, *,
         new_groups.append(new_layers)
 
     logits = _lm_head(params, spec, x)
-    new_cache = {"pos": pos + 1, "groups": new_groups}
+    new_cache = {**cache, "pos": pos + 1, "groups": new_groups}
     return logits, new_cache

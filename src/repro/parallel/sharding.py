@@ -306,6 +306,10 @@ class ShardingRules:
         out = {"pos": NamedSharding(mesh, P()), "groups": []}
         if "block_tables" in cache:
             out["block_tables"] = NamedSharding(mesh, P(None, None))
+        for name in cache:
+            if name not in out:       # per-slot conv state, expert counts
+                out[name] = jax.tree_util.tree_map(
+                    lambda _: NamedSharding(mesh, P()), cache[name])
         for g in cache["groups"]:
             layers = []
             for entry_dict in g:
@@ -323,6 +327,7 @@ class ShardingRules:
         """True iff a paged cache's pools actually shard over "model"
         (KV-head divisibility held) — the gate for running the paged
         attention per shard under ``shard_map``."""
-        entry = cache["groups"][0][0]
+        entry = next(e for g in cache["groups"] for e in g
+                     if "k_pages" in e)
         ps = self.cache_entry_pspec("k_pages", entry["k_pages"].shape)
         return "model" in tuple(ps)

@@ -83,10 +83,15 @@ def _admit_fn(params, batch, cache, slot, true_len, bt_row, *, spec, impl,
     window horizon (the last R pages), and routes to the null page
     otherwise (the sliding window can never read it); padding pages
     past ``true_len`` also go to null so they never collide with a live
-    ring entry."""
+    ring entry.
+
+    Conv layers' state at the prompt's true end lands in the slot's row
+    of ``cache["conv"]``; routed-expert layers' rows per held expert
+    replace ``cache["expert_rows"]``."""
+    rows = []
     logits, pre = lm.prefill(params, spec, batch,
                              max_seq=batch["tokens"].shape[1],
-                             impl=impl, true_len=true_len)
+                             impl=impl, true_len=true_len, expert_rows=rows)
     page = lm.paged_page_size(cache)
     n = batch["tokens"].shape[1] // page          # prompt pages (static)
     if ring:
@@ -100,11 +105,35 @@ def _admit_fn(params, batch, cache, slot, true_len, bt_row, *, spec, impl,
     new_groups = pc.scatter_prompt_pages(cache["groups"], pre["groups"],
                                          pv, page)
     new_cache = {
+        **cache,
         "pos": cache["pos"].at[slot].set(true_len),
         "block_tables": cache["block_tables"].at[slot].set(bt_row),
         "groups": new_groups,
     }
+    states = [e["conv_state"][0] for g in pre["groups"] for e in g
+              if "conv_state" in e]
+    if states:
+        new_cache["conv"] = [c.at[slot].set(st)
+                             for c, st in zip(cache["conv"], states)]
+    _put_expert_rows(new_cache, rows)
     return jnp.argmax(logits[0, 0]), new_cache
+
+
+def _put_expert_rows(cache, rows) -> None:
+    """Install one call's rows per held expert (a list, one (H,) array
+    per routed layer) as ``cache["expert_rows"]``."""
+    if rows:
+        cache["expert_rows"] = jnp.stack(rows)
+
+
+def _keep_idle_state(cache, conv, active) -> None:
+    """Keep the conv state of slots that were not decoded: a mid-prefill
+    slot carries its state between chunks, and an idle session slot
+    rejoins from it."""
+    if conv:
+        live = active[:, None, None] > 0
+        cache["conv"] = [jnp.where(live, new, old)
+                         for new, old in zip(cache["conv"], conv)]
 
 
 @functools.partial(jax.jit,
@@ -117,9 +146,12 @@ def _admit_prefix_fn(params, batch, cache, slot, prefix_len, true_len,
     first token.  Retraces per (suffix bucket, prefix-page bucket).
     ``ring=True`` follows the ring entry mapping for both the prefix
     gather and the suffix scatter (see ``lm.prefill_paged``)."""
+    rows = []
     logits, new_cache = lm.prefill_paged(
         params, spec, batch["tokens"], cache, slot, bt_row, prefix_len,
-        true_len, n_prefix_pages=n_prefix_pages, ring=ring, mesh=mesh)
+        true_len, n_prefix_pages=n_prefix_pages, ring=ring, mesh=mesh,
+        expert_rows=rows)
+    _put_expert_rows(new_cache, rows)
     return jnp.argmax(logits[0, 0]), new_cache
 
 
@@ -128,8 +160,14 @@ def _admit_prefix_fn(params, batch, cache, slot, prefix_len, true_len,
                    donate_argnums=(1,))
 def _decode_fn(params, cache, tokens, active, *, spec, mesh=None,
                shard_params=False, ring=False):
+    rows, conv = [], cache.get("conv")
+    # idle slots are routed nowhere, so the expert counters count only
+    # the slots this step decodes
     logits, cache = lm.decode_step(params, spec, cache, tokens, mesh=mesh,
-                                   shard_params=shard_params, ring=ring)
+                                   shard_params=shard_params, ring=ring,
+                                   valid=active > 0, expert_rows=rows)
+    _put_expert_rows(cache, rows)
+    _keep_idle_state(cache, conv, active)
     # pin inactive slots at pos 0 so their (clamped) block-table lookups
     # stay on the null page indefinitely
     cache["pos"] = cache["pos"] * active
@@ -158,9 +196,12 @@ def _decode_window_fn(params, cache, tokens, active, lens, *, spec,
     token-for-token what sequential greedy decode would produce.
     """
     pos0 = cache["pos"]
+    rows = []
     logits, cache = lm.decode_window_paged(params, spec, cache, tokens,
                                            lens, ring=ring, mesh=mesh,
-                                           shard_params=shard_params)
+                                           shard_params=shard_params,
+                                           expert_rows=rows)
+    _put_expert_rows(cache, rows)
     out = jnp.argmax(logits, axis=-1)                       # (B, K)
     K = tokens.shape[1]
     j = jnp.arange(K - 1)
@@ -210,8 +251,13 @@ def _scatter_pages_fn(cache, rows, pv):
                 new_entry[name] = entry[name].at[pv].set(src[name])
             new_layers.append(new_entry)
         new_groups.append(new_layers)
-    return {"pos": cache["pos"], "block_tables": cache["block_tables"],
-            "groups": new_groups}
+    return {**cache, "groups": new_groups}
+
+
+#: Counters of the routed-expert layers, summed over layers and calls:
+#: rows the held experts computed, distinct held experts each layer
+#: call hit, and routed-layer calls.
+EXPERT_COUNTERS = ("moe_rows", "moe_experts_hit", "moe_layer_calls")
 
 
 class PagedKVBackend:
@@ -228,9 +274,24 @@ class PagedKVBackend:
 
     def bind_spans(self, recorder) -> None:
         """Record this backend's device calls as spans of ``recorder``
-        (the owning engine's ``spans.SpanRecorder``); until an engine
-        binds one, they record nothing."""
+        (the owning engine's ``spans.SpanRecorder``), and a routed-expert
+        spec's ``EXPERT_COUNTERS`` in the recorder's ``stats``; until an
+        engine binds one, they record nothing."""
         self._spans = recorder
+        if self.spec.moe is not None:
+            for name in EXPERT_COUNTERS:
+                recorder.stats.setdefault(name, 0)
+
+    def _count_experts(self) -> None:
+        """Add the last call's rows per held expert (the cache's
+        ``expert_rows``) to the bound engine's counters."""
+        stats = getattr(self._spans, "stats", None)
+        if stats is None or "expert_rows" not in self.cache:
+            return
+        rows = np.asarray(self.cache["expert_rows"])
+        stats["moe_rows"] += int(rows.sum())
+        stats["moe_experts_hit"] += int(np.count_nonzero(rows))
+        stats["moe_layer_calls"] += rows.shape[0]
 
     def admit_full(self, padded_tokens: np.ndarray, slot: int,
                    true_len: int, bt_row: np.ndarray) -> int:
@@ -403,7 +464,9 @@ class SingleDeviceBackend(PagedKVBackend):
                 self.params, {"tokens": jnp.asarray(padded_tokens)},
                 self.cache, jnp.int32(slot), jnp.int32(true_len),
                 jnp.asarray(bt_row))
-            return int(tok0)
+            tok0 = int(tok0)
+            self._count_experts()
+            return tok0
 
     def admit_prefix(self, padded_suffix, slot, prefix_len, true_len,
                      bt_row, *, n_prefix_pages) -> int:
@@ -413,7 +476,9 @@ class SingleDeviceBackend(PagedKVBackend):
                 self.cache, jnp.int32(slot), jnp.int32(prefix_len),
                 jnp.int32(true_len), jnp.asarray(bt_row),
                 n_prefix_pages=n_prefix_pages)
-            return int(tok0)
+            tok0 = int(tok0)
+            self._count_experts()
+            return tok0
 
     def prefill_chunk(self, padded_chunk, slot, prefix_len, true_len,
                       bt_row, *, n_prefix_pages) -> int:
@@ -436,14 +501,18 @@ class SingleDeviceBackend(PagedKVBackend):
                     self.params, self.cache, jnp.asarray(tokens),
                     jnp.asarray(active))
             with span("backend.decode_wait"):
-                return (np.asarray(nxt)[:, None],
-                        np.asarray(active, np.int32), np.asarray(ok))
+                out = (np.asarray(nxt)[:, None],
+                       np.asarray(active, np.int32), np.asarray(ok))
+                self._count_experts()
+                return out
         with span("backend.decode_dispatch"):
             out, n_emit, ok, self.cache = self._decode_window(
                 self.params, self.cache, jnp.asarray(tokens),
                 jnp.asarray(active), jnp.asarray(lens))
         with span("backend.decode_wait"):
-            return np.asarray(out), np.asarray(n_emit), np.asarray(ok)
+            out = np.asarray(out), np.asarray(n_emit), np.asarray(ok)
+            self._count_experts()
+            return out
 
     def copy_page(self, src_page: int, dst_page: int) -> None:
         with self._spans.span("backend.page_table"):
@@ -513,6 +582,10 @@ class ShardedPagedBackend(SingleDeviceBackend):
                  tp: Optional[int] = None,
                  devices: Optional[List] = None):
         from repro.parallel.sharding import ShardingRules
+        if spec.num_conv_layers():
+            raise NotImplementedError(
+                f"{spec.name}: tensor-parallel serving has no layout for "
+                "per-slot conv state; serve it with devices=1")
         devices = devices if devices is not None else jax.devices()
         tp = tp if tp is not None else len(devices)
         if tp < 2:

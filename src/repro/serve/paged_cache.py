@@ -549,12 +549,16 @@ def scatter_prompt_pages(cache_groups, prefill_groups, pv: jnp.ndarray,
     scheduler's fused jitted admission go through it.  Quantized pools
     quantize rows and fill the scale pools alongside; int4 additionally
     nibble-packs token pairs (whole pages are written, so no
-    read-modify-write is needed here)."""
+    read-modify-write is needed here).  Layers without pages (conv)
+    pass through."""
     n = pv.shape[0]
     new_groups = []
     for cg, pg in zip(cache_groups, prefill_groups):
         new_layers = []
         for entry, src in zip(cg, pg):
+            if "k_pages" not in entry:
+                new_layers.append(entry)
+                continue
             quant = lm._paged_quant(entry)
             new_entry = dict(entry)
             for name in ("k", "v"):
@@ -592,8 +596,7 @@ def _copy_page_fn(cache, src, dst):
                 new_entry[name] = pool.at[dst].set(pool[src])
             new_layers.append(new_entry)
         new_groups.append(new_layers)
-    return {"pos": cache["pos"], "block_tables": cache["block_tables"],
-            "groups": new_groups}
+    return {**cache, "groups": new_groups}
 
 
 def copy_page(cache, src_page: int, dst_page: int):
@@ -616,6 +619,7 @@ def write_prompt(cache, spec: ModelSpec, slot: int, pages: Sequence[int],
     row = jnp.full((bt.shape[1],), NULL_PAGE, jnp.int32)
     row = row.at[:len(pages)].set(pv)
     return {
+        **cache,
         "pos": cache["pos"].at[slot].set(jnp.int32(true_len)),
         "block_tables": bt.at[slot].set(row),
         "groups": new_groups,
@@ -626,7 +630,7 @@ def release_slot(cache, slot: int):
     """Reset a finished slot's block table/pos to the null page (device
     side only — the allocator frees the physical pages)."""
     return {
+        **cache,
         "pos": cache["pos"].at[slot].set(0),
         "block_tables": cache["block_tables"].at[slot].set(NULL_PAGE),
-        "groups": cache["groups"],
     }

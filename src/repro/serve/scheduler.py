@@ -79,6 +79,13 @@ runs the vLLM-style alternative on top of the paged KV cache:
   greedy decode (asserted in tests/test_spec_decode.py and the
   ``benchmarks/serve_throughput.py --spec-decode`` gate).
 
+* a spec with gated short-convolution layers keeps a per-slot conv
+  state beside the pages that only the slot's own prefill writes: a
+  page-only prefix hit or a swapped-in slot could not restore it, nor
+  a verify window roll it back, so the engine builds no prefix cache
+  and no host pool for such a spec (preemption recomputes; the stats
+  say why, ``off.*.conv_state``) and refuses ``spec_k > 1``;
+
 * the request LIFECYCLE is typed and fault-aware: ``Request`` carries
   an optional ``deadline_s`` (queued work past its deadline is SHED
   with a typed ``Completion(status="shed")`` instead of admitted late)
@@ -337,9 +344,17 @@ class ContinuousBatchingEngine:
         self.window = int(getattr(self.backend, "window", 0) or 0)
         self.ring = bool(getattr(self.backend, "ring", False))
         self.alloc = pc.PageAllocator(self.layout.num_pages)
+        # per-slot conv state comes only from the slot's own prefill: a
+        # page-only prefix hit, a swapped-in slot and a rolled-back
+        # verify window would each resume from state it never had
+        stateful = spec.num_conv_layers() > 0
+        if stateful and cfg.spec_k > 1:
+            raise ValueError(
+                f"{spec.name}: spec_k={cfg.spec_k} needs verify windows, "
+                "which cannot roll back per-slot conv state; use spec_k=1")
         self.prefix_cache: Optional[pc.PrefixCache] = (
             pc.PrefixCache(self.alloc, cfg.page_size)
-            if cfg.enable_prefix_cache else None)
+            if cfg.enable_prefix_cache and not stateful else None)
         self.slots: List[Optional[_Slot]] = [None] * cfg.max_slots
         self.queue: Deque[Request] = deque()
         self._resume: Dict[int, _Resume] = {}
@@ -348,7 +363,7 @@ class ContinuousBatchingEngine:
         # ("uid", uid)) and idle sessions (keyed ("sess", session))
         self.host_pool: Optional[pc.HostPagePool] = (
             pc.HostPagePool(cfg.host_pool_bytes)
-            if cfg.host_pool_bytes else None)
+            if cfg.host_pool_bytes and not stateful else None)
         self._host_page_bytes = (self.backend.host_page_bytes()
                                  if self.host_pool is not None else 0)
         self.stats: Dict[str, float] = {
@@ -393,6 +408,14 @@ class ContinuousBatchingEngine:
             # released for a fresh one (prefix store / other holders
             # kept the bytes; nothing was stolen)
             "ring_recycled_pages": 0, "ring_shared_released": 0}
+        if stateful:
+            # what was turned off, and why: a prefix hit restores pages
+            # but no conv state, and a parked slot's state is not
+            # swapped (preemption recomputes instead)
+            if cfg.enable_prefix_cache:
+                self.stats["off.prefix_cache.conv_state"] = 1
+            if cfg.host_pool_bytes:
+                self.stats["off.host_pool.conv_state"] = 1
         # self time and calls of each phase of a step and each backend
         # call, as ``self_s:<span>`` / ``calls:<span>`` keys of stats
         # (serve/spans.py); every span is also a profiler annotation

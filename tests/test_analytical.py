@@ -738,3 +738,49 @@ def test_serve_availability_goodput_clips_to_degraded_capacity():
     assert heavy["goodput_tokens_per_s"] == pytest.approx(
         cap["degraded_tokens_per_s"])
     assert heavy["goodput_fraction"] == pytest.approx(0.5)
+
+
+def test_lfm2_moe_counts_and_serve_terms():
+    """LFM2-8B-A1B: published totals, the held share's parameters and
+    active FLOPs, leading dense layers, the weights a decode step
+    streams (expected distinct held experts hit at a batch) and the
+    per-slot conv state."""
+    import dataclasses
+    from repro.core.hardware import TPU_V5E
+    from repro.core.latency import mixed_iteration_cost
+    full = ARCHS["lfm2-8b-a1b"]
+    assert 8.3e9 < blocks.param_count(full, padded=False) < 8.4e9     # 8.3B
+    assert 1.5e9 < blocks.active_param_count(full) < 1.6e9          # A1.5B
+    spec = full.with_(moe=dataclasses.replace(full.moe, held=(0, 16)))
+    assert blocks.param_count(spec, padded=False) == pytest.approx(4.464e9,
+                                                                  rel=1e-3)
+    assert [spec.moe_layer(i) for i in range(4)] == [False, False, True,
+                                                     True]
+    per_expert = 3 * 2048 * 1792
+    # each token computes top_k * held / num_experts = 2 experts here
+    moe = blocks.moe_flops_per_token(spec)
+    assert moe == pytest.approx(2 * 2048 * 32 + 2 * (2 * per_expert
+                                                     + 4 * 1792))
+    # a held expert is missed by all 32 rows with probability (7/8)**32
+    hit = analytical.expected_experts_hit(spec, 32)
+    assert hit == pytest.approx(16 * (1 - (7 / 8) ** 32))
+    P = blocks.param_count(spec, padded=False)
+    assert analytical.streamed_params(spec, 32) == pytest.approx(
+        P - 22 * (16 - hit) * per_expert)
+    assert analytical.streamed_params(spec, 1) < 0.3 * P
+    assert analytical.conv_state_bytes(spec, 32) == 32 * 18 * 2 * 2048 * 4
+    plan = analytical.PagedCachePlan(page_size=16, num_pages=8193,
+                                     page_bytes=393216.0,
+                                     bytes_per_token=393216.0 / 16)
+    c = mixed_iteration_cost(spec, TPU_V5E, get_precision("bf16"), plan,
+                             prefill_tokens=0, decode_slots=32,
+                             avg_context=1000)
+    want = (2 * analytical.streamed_params(spec, 32)
+            + plan.bytes_per_token * 32 * 1000
+            + 2 * analytical.conv_state_bytes(spec, 32))
+    assert c.memory_s == pytest.approx(want / (TPU_V5E.mem_bw
+                                               * TPU_V5E.u_memory))
+    # the dense stack is unchanged: every weight streams every step
+    dense = ASSIGNED["granite-3-8b"]
+    assert analytical.streamed_params(dense, 1) == blocks.param_count(
+        dense, padded=False)

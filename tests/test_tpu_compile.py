@@ -81,3 +81,24 @@ def test_paged_attention_compiles_at_benchmark_widths(one_chip, cache_dtype,
     kernel = functools.partial(paged_attention_pallas, interpret=False)
     compiled = jax.jit(kernel).lower(*args, **scales).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_routed_experts_compile_at_lfm2_widths(one_chip):
+    """The dropless routed-expert layer at LFM2-8B-A1B's decode widths
+    (32 rows, top-4 of 32 experts, 16 held, d 2048, experts 1792 wide,
+    bf16): its grouped matmuls lower to the TPU's ``ragged-dot`` ops,
+    the names the benchmark's ``moe_roofline.lfm2`` reads."""
+    import dataclasses
+    from repro.configs import ARCHS
+    from repro.models import layers as L
+    arch = ARCHS["lfm2-8b-a1b"]
+    spec = arch.with_(moe=dataclasses.replace(arch.moe, held=(0, 16)))
+    d, f, E = spec.d_model, spec.moe.expert_ff, spec.moe.num_experts
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    p = {"router_w": sds((d, E)), "expert_bias": sds((E,)),
+         "experts_wi": sds((16, d, 2 * f)), "experts_wo": sds((16, f, d))}
+    compiled = jax.jit(functools.partial(L.routed_experts, spec)).lower(
+        p, sds((32, 1, d))).compile()
+    assert "ragged-dot" in compiled.as_text()
