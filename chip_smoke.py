@@ -244,7 +244,8 @@ def _paged_prefill(params, cache, prefix, suffix, suffix_len, row, *, spec,
     _, pre = lm.prefill(params, spec, {"tokens": prefix}, impl="naive")
     groups = pc.scatter_prompt_pages(cache["groups"], pre["groups"],
                                      row[:n_prefix_pages],
-                                     lm.paged_page_size(cache))
+                                     lm.paged_page_size(cache,
+                                                        spec.head_dim))
     cache = {"pos": cache["pos"], "block_tables": cache["block_tables"],
              "groups": groups}
     return lm.prefill_paged(params, spec, suffix, cache, 0, row,

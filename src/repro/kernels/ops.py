@@ -13,7 +13,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref
+from repro.kernels import page_layout, ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.quant_matmul import quant_matmul_pallas
@@ -143,7 +143,8 @@ def paged_attention_sharded(mesh, q, k_pages, v_pages, block_tables,
     # (B, K, H, D) — same head axis, one extra replicated window dim
     qs = (P(None, None, axis, None) if q.ndim == 4
           else P(None, axis, None))
-    ps = P(None, None, axis, None)                # pools: KV-head dim
+    kv = page_layout.kv_axis(k_pages.shape, q.shape[-1])
+    ps = P(*[axis if i == kv else None for i in range(4)])  # pools: KV dim
     ss = P(None, axis, None)                      # lane-major scales
     bs, ls = P(None, None), P(None)
     if k_scale is not None:

@@ -35,7 +35,11 @@ on a memory-bound decode roofline.
 A page is copied as its (page * KV, D) rows in (token, kv head) order,
 so one matmul scores every (query, head) row against every K row of a
 chunk and a mask keeps each head's own kv head (GQA), with no transpose
-in VMEM.  Online softmax carries f32 (m, l, acc) scratch across chunks,
+in VMEM.  Pools stored lane-dense (``page_layout``: head_dim under 128
+lanes, each kv head's page whole rows of 128) are copied as they lie,
+(KV * rows, 128) a page, and each 128-lane row is scored as the g =
+128 / D tokens it holds side by side.  Online softmax carries f32
+(m, l, acc) scratch across chunks,
 exactly like ``flash_attention.py``.  Every row is masked by its
 absolute token position, and in the chunk that holds the end of the
 live range, the pages past it are zero-filled instead of copied, so they
@@ -80,6 +84,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import page_layout
+
 NEG_INF = -1e30
 
 #: Tokens a block of pages aims to hold (the table and VMEM permitting).
@@ -107,11 +113,12 @@ def pages_per_block(page: int, n_entries: int, page_shape, page_dtype,
     """Pages per streamed block: ``BLOCK_TOKENS // page`` (at least
     one), at most ``n_entries``, halved until K and V (and their scale
     pages), double-buffered, fit ``BLOCK_VMEM_BYTES``.  ``page_shape``
-    is one pool page, (page or page//2, KV, D)."""
-    tok, kv, d = page_shape
-    per_page = _tile_bytes(tok * kv, d, page_dtype)
-    if scaled:
-        per_page += _tile_bytes(kv, page, jnp.float32)
+    is one pool page, (page or page//2, KV, D) or lane-dense
+    (KV, rows, 128)."""
+    per_page = _tile_bytes(int(np.prod(page_shape[:-1])), page_shape[-1],
+                           page_dtype)
+    if scaled:                  # (page or page//2, KV, D) pages
+        per_page += _tile_bytes(page_shape[1], page, jnp.float32)
     n = max(1, min(n_entries, BLOCK_TOKENS // page))
     while n > 1 and 2 * 2 * n * per_page > BLOCK_VMEM_BYTES:
         n //= 2
@@ -161,6 +168,11 @@ def _idiv(x, n: int):
     return jnp.floor((x.astype(jnp.float32) + 0.5) / n).astype(jnp.int32)
 
 
+def _imod(x, n: int):
+    """x % n for non-negative int32 vectors."""
+    return x - _idiv(x, n) * n
+
+
 def _unpack_nibbles(packed: jnp.ndarray, tokens: int) -> jnp.ndarray:
     """(tokens//2, KV, D) packed int8 -> (tokens, KV, D) f32 in [-8, 7].
 
@@ -197,15 +209,13 @@ def _dequant_page(x, xs, quant: str, page: int, kv: int):
     return (x * s).reshape(t * kv, D)
 
 
-def _chunk_tokens(e0, lo, cnt, last, length, page: int, kv: int, n: int,
-                  n_entries: int, mode: str):
-    """(tok, live): the absolute token position (1, T) of each of the
-    T = n * page * kv (token, kv head) rows of the n entries from ``e0``
-    on, and whether it lies in an entry the slot streams.  Entries map
-    to absolute pages linearly except across a ring's wrap point
-    (entries past ``last mod R`` hold the previous lap)."""
-    t = _idiv(jax.lax.broadcasted_iota(jnp.int32, (1, n * page * kv), 1),
-              kv)
+def _chunk_tokens(t, e0, lo, cnt, last, length, page: int, n_entries: int,
+                  mode: str):
+    """(tok, live): the absolute token position of each column whose
+    token lies ``t`` tokens after the first of entry ``e0``, and whether
+    it lies in an entry the slot streams.  Entries map to absolute pages
+    linearly except across a ring's wrap point (entries past
+    ``last mod R`` hold the previous lap)."""
     live = t < (lo + cnt - e0) * page
     tok = e0 * page + t
     if mode == "ring":
@@ -297,14 +307,21 @@ def _stream(bt_ref, len_ref, pages, scales, bufs, sem, *, page: int,
 
 def _paged_kernel(bt_ref, len_ref, q_ref, *rest, scale: float, page: int,
                   ppb: int, cpb: int, n_entries: int, span: int, window: int,
-                  kv_heads: int, heads: int, wq: int, quant: str, mode: str):
+                  kv_heads: int, heads: int, wq: int, quant: str, mode: str,
+                  dense_rows: int):
     """One slot's decode attention.  q rows are (query, head) pairs:
     query j of the K-token window (K = ``wq``, 1 for the plain step)
     sits at absolute position ``length - K + j`` and attends positions
     up to its own (causal inside the window), within the sliding
     ``window`` if one is set.  Each chunk's K/V arrive as (token, kv
     head) rows, so one matmul scores every query head against every row
-    and a mask keeps the rows of the head's own kv head (GQA)."""
+    and a mask keeps the rows of the head's own kv head (GQA).
+
+    Lane-dense pages (``dense_rows`` > 0, ``page_layout``) arrive as
+    ``dense_rows`` rows of 128 lanes per kv head, each holding g = 128/D
+    consecutive tokens side by side: lanes [s·D, (s+1)·D) of every row
+    are scored as one set of columns, token s of the row, and all g sets
+    fold into the same online softmax."""
     n = 2 if quant == "none" else 4
     ops, (o_ref, *rest) = rest[:n], rest[n:]
     bufs, (sem, m_ref, l_ref, acc_ref) = rest[:n], rest[n:]
@@ -315,33 +332,51 @@ def _paged_kernel(bt_ref, len_ref, q_ref, *rest, scale: float, page: int,
     length = len_ref[pl.program_id(0)]
     lo, cnt, last = _live_range(length, page, n_entries, span)
     q = q_ref[0].astype(jnp.float32) * scale               # (R, D)
+    D = q.shape[1]
     row = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], 1), 0)
     q_pos = length - wq + _idiv(row, heads)                 # (R, 1)
-    q_kv = _idiv(row - _idiv(row, heads) * heads, heads // kv_heads)
+    q_kv = _idiv(_imod(row, heads), heads // kv_heads)
 
-    def compute(arrays, e0):
+    def columns(arrays):
+        """[(k, v, t, head)]: each set of key/value columns (T, D) with
+        its token's offset ``t`` from the chunk's first token and its kv
+        head, both (1, T)."""
         k, ks, v, vs = ((arrays[0], None, arrays[1], None)
                         if quant == "none" else arrays)
-        k = _dequant_page(k, ks, quant, page, kv_heads)     # (T, D)
+        k = _dequant_page(k, ks, quant, page, kv_heads)     # (T, lanes)
         v = _dequant_page(v, vs, quant, page, kv_heads)
-        tok, live = _chunk_tokens(e0, lo, cnt, last, length, page, kv_heads,
-                                  cpb, n_entries, mode)
-        col = jax.lax.broadcasted_iota(jnp.int32, tok.shape, 1)
-        valid = live & (tok <= q_pos) & (
-            col - _idiv(col, kv_heads) * kv_heads == q_kv)
-        if window:
-            valid &= (q_pos - tok) < window
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = jnp.where(valid, s, NEG_INF)                    # (R, T)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, k.shape[0]), 1)
+        if not dense_rows:
+            return [(k, v, _idiv(col, kv_heads), _imod(col, kv_heads))]
+        per_row = k.shape[1] // D
+        t = (_idiv(col, kv_heads * dense_rows) * page
+             + _imod(col, dense_rows) * per_row)
+        head = _imod(_idiv(col, dense_rows), kv_heads)
+        return [(k[:, s * D:(s + 1) * D], v[:, s * D:(s + 1) * D], t + s,
+                 head) for s in range(per_row)]
+
+    def compute(arrays, e0):
+        scored = []
+        for k, v, t, head in columns(arrays):
+            tok, live = _chunk_tokens(t, e0, lo, cnt, last, length, page,
+                                      n_entries, mode)
+            valid = live & (tok <= q_pos) & (head == q_kv)
+            if window:
+                valid &= (q_pos - tok) < window
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            scored.append((jnp.where(valid, s, NEG_INF), valid, v))  # (R, T)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        e = jnp.exp(s - m_new) * valid.astype(jnp.float32)
+        m_new = m_prev
+        for s, _, _ in scored:
+            m_new = jnp.maximum(m_new, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(e, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            e, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        l, acc = alpha * l_ref[...], acc_ref[...] * alpha
+        for s, valid, v in scored:
+            e = jnp.exp(s - m_new) * valid.astype(jnp.float32)
+            l = l + jnp.sum(e, axis=-1, keepdims=True)
+            acc = acc + jnp.dot(e, v, preferred_element_type=jnp.float32)
+        l_ref[...], acc_ref[...], m_ref[...] = l, acc, m_new
 
     _stream(bt_ref, len_ref, pages, scales, bufs, sem, page=page, ppb=ppb,
             cpb=cpb, n_entries=n_entries, span=span, compute=compute)
@@ -365,7 +400,8 @@ def paged_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
     """q: (B, H, D) single-query, or (B, K, H, D) for a K-token decode
     window (``lengths`` then counts the context INCLUDING the window;
     query j attends positions <= lengths - K + j); k_pages/v_pages:
-    (P, page, KV, D) float — or int8 with lane-major
+    (P, page, KV, D) float, or lane-dense (P, KV, page·D/128, 128) float
+    (``page_layout``) — or int8 with lane-major
     ``k_scale``/``v_scale`` (P, KV, page) f32, or nibble-packed int4
     (P, page//2, KV, D) (packing inferred from the scale's token dim);
     block_tables: (B, pages_per_slot) int32; lengths: (B,) int32.
@@ -385,7 +421,10 @@ def paged_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
     else:
         WQ = 0                                # single-query kernel
         B, H, D = q.shape
-    P, tok, KV, _ = k_pages.shape
+    P = k_pages.shape[0]
+    tok, KV = page_layout.pool_dims(k_pages.shape, D)
+    dense_rows = (k_pages.shape[-2]
+                  if page_layout.is_lane_dense(k_pages.shape, D) else 0)
     if k_scale is not None:
         page = k_scale.shape[-1]
         quant = "int8" if tok == page else "int4"
@@ -398,10 +437,11 @@ def paged_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
         quant = "none"
     n_entries = block_tables.shape[1]
     sc = scale if scale is not None else 1.0 / (D ** 0.5)
-    if D % 128:
+    if D % 128 and not dense_rows:
         # a page is copied whole, so its minor dim has to fill the
         # 128 lanes: zero-pad head_dim (q's pad meets zeros, v's pad
-        # lands in output lanes that are sliced off)
+        # lands in output lanes that are sliced off).  Only for pools
+        # ``page_layout`` does not store lane-dense.
         def pad(x):
             return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, -D % 128)])
         o = paged_attention_pallas(
@@ -418,15 +458,16 @@ def paged_attention_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
     kernel = functools.partial(
         _paged_kernel, scale=sc, page=page, ppb=ppb, cpb=cpb,
         n_entries=n_entries, span=span, window=window, kv_heads=KV, heads=H,
-        wq=max(WQ, 1), quant=quant, mode=mode)
+        wq=max(WQ, 1), quant=quant, mode=mode, dense_rows=dense_rows)
 
-    # K/V pools stay in HBM, each page one (page * KV, D) copy; the small
-    # scale pools ride in VMEM whole (an HBM page slice narrower than 128
-    # lanes cannot be a copy's source)
+    # K/V pools stay in HBM, each page one (page * KV, D) copy, or
+    # (KV * dense_rows, 128) lane-dense; the small scale pools ride in
+    # VMEM whole (an HBM page slice narrower than 128 lanes cannot be a
+    # copy's source)
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     vmem = pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM)
-    k_rows = k_pages.reshape(P, tok * KV, D)
-    v_rows = v_pages.reshape(P, tok * KV, D)
+    k_rows = k_pages.reshape(P, -1, k_pages.shape[-1])
+    v_rows = v_pages.reshape(P, -1, v_pages.shape[-1])
     if quant == "none":
         operands, specs = [k_rows, v_rows], [hbm, hbm]
     else:

@@ -7,6 +7,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import page_layout
 from repro.quant.qtypes import QuantizedTensor
 from repro.quant.quantize import dequantize, quantize_values
 
@@ -66,7 +67,8 @@ def paged_attention_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
                         v_scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Gather-based paged decode attention (one query token per slot).
 
-    q: (B, H, D); k_pages/v_pages: (P, page, KV, D); block_tables:
+    q: (B, H, D); k_pages/v_pages: (P, page, KV, D), or lane-dense
+    (P, KV, page·D/128, 128) (``page_layout``); block_tables:
     (B, pages_per_slot) page ids into the pool; lengths: (B,) number of
     valid context tokens per slot (the current token's k/v already
     written).  Fully-masked slots (length 0) return zeros.  For int8
@@ -88,15 +90,18 @@ def paged_attention_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
             ring=ring, scale=scale, k_scale=k_scale, v_scale=v_scale)
     from repro.quant.quantize import unpack_int4
     B, H, D = q.shape
-    KV = k_pages.shape[2]
-    page = k_scale.shape[-1] if k_scale is not None else k_pages.shape[1]
+    page, KV = page_layout.pool_dims(k_pages.shape, D)
+    if k_scale is not None:
+        page = k_scale.shape[-1]
     if k_scale is not None and k_pages.shape[1] != page:     # packed int4
         k_pages = unpack_int4(k_pages, axis=1)
         v_pages = unpack_int4(v_pages, axis=1)
     G = H // KV
     sc = scale if scale is not None else 1.0 / (D ** 0.5)
-    k = k_pages[block_tables].astype(jnp.float32)      # (B, n, page, KV, D)
-    v = v_pages[block_tables].astype(jnp.float32)
+    k = page_layout.pages_from_pool(k_pages[block_tables], D).astype(
+        jnp.float32)                                   # (B, n, page, KV, D)
+    v = page_layout.pages_from_pool(v_pages[block_tables], D).astype(
+        jnp.float32)
     if k_scale is not None:
         # lane-major (B, n, KV, page) -> broadcastable (B, n, page, KV, 1)
         k = k * jnp.moveaxis(k_scale[block_tables], -1, -2)[..., None]
@@ -148,15 +153,18 @@ def paged_attention_window_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
     """
     from repro.quant.quantize import unpack_int4
     B, K, H, D = q.shape
-    KV = k_pages.shape[2]
-    page = k_scale.shape[-1] if k_scale is not None else k_pages.shape[1]
+    page, KV = page_layout.pool_dims(k_pages.shape, D)
+    if k_scale is not None:
+        page = k_scale.shape[-1]
     if k_scale is not None and k_pages.shape[1] != page:     # packed int4
         k_pages = unpack_int4(k_pages, axis=1)
         v_pages = unpack_int4(v_pages, axis=1)
     G = H // KV
     sc = scale if scale is not None else 1.0 / (D ** 0.5)
-    k = k_pages[block_tables].astype(jnp.float32)      # (B, n, page, KV, D)
-    v = v_pages[block_tables].astype(jnp.float32)
+    k = page_layout.pages_from_pool(k_pages[block_tables], D).astype(
+        jnp.float32)                                   # (B, n, page, KV, D)
+    v = page_layout.pages_from_pool(v_pages[block_tables], D).astype(
+        jnp.float32)
     if k_scale is not None:
         k = k * jnp.moveaxis(k_scale[block_tables], -1, -2)[..., None]
     if v_scale is not None:

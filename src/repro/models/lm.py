@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.core import blocks
 from repro.core.model_config import ModelSpec
+from repro.kernels import page_layout
 from repro.models import layers as L
 from repro.models import recurrent as R
 from repro.models.scan_util import scan as _scan
@@ -306,15 +307,29 @@ def _norm_cache_dtype(dtype) -> str:
     return "int8" if dtype == jnp.int8 else "fp32"
 
 
-def paged_page_size(cache) -> int:
-    """Token capacity of one page — from the scale pool for quantized
-    caches (the int4 value pool's token dim is nibble-packed to half).
-    Scale pools are LANE-MAJOR (P, KV, page): token dim last.  Conv
-    layers' entries hold no pages."""
+def paged_page_size(cache, head_dim: int) -> int:
+    """Token capacity of one page of a cache storing heads of
+    ``head_dim``.  Conv layers' entries hold no pages."""
     entry = next(e for g in cache["groups"] for e in g if "k_pages" in e)
+    return _entry_page(entry, head_dim)
+
+
+def lane_dense_layers(cache, head_dim: int) -> int:
+    """Attention layers of a paged cache whose pools are stored
+    lane-dense (``kernels.page_layout``)."""
+    return sum(page_layout.is_lane_dense(e["k_pages"].shape, head_dim)
+               for g in cache["groups"] for e in g if "k_pages" in e)
+
+
+def _entry_page(entry, head_dim: int) -> int:
+    """Token capacity of one page of a layer's pools — from the scale
+    pool for quantized caches (the int4 value pool's token dim is
+    nibble-packed to half; scale pools are LANE-MAJOR (P, KV, page),
+    token dim last), else from the pool in either layout
+    (``kernels.page_layout``)."""
     if "k_scale" in entry:
         return entry["k_scale"].shape[-1]
-    return entry["k_pages"].shape[1]
+    return page_layout.pool_dims(entry["k_pages"].shape, head_dim)[0]
 
 
 def _paged_quant(entry) -> str:
@@ -349,8 +364,10 @@ def init_paged_cache(spec: ModelSpec, batch: int, max_seq: int,
     layout) so a page moves ~8x fewer bytes than fp32.  Scale pools are
     LANE-MAJOR (P, KV, page) — token dim last, so one page's scales sit
     in a single (8, 128) f32 tile on real TPU instead of tile-padding a
-    (page, KV, 1) block per token.  ``pos`` is a PER-SLOT length
-    vector, not a scalar.
+    (page, KV, 1) block per token.  Float pools whose head_dim is under
+    128 lanes are stored lane-dense, (P, KV, page·D/128, 128), where
+    ``kernels.page_layout.lane_dense_rows`` allows it.  ``pos`` is a
+    PER-SLOT length vector, not a scalar.
     """
     for kind in spec.layer_kinds():
         if _base_kind(kind) not in ("attn", "attn_local", "attn_global",
@@ -375,7 +392,7 @@ def init_paged_cache(spec: ModelSpec, batch: int, max_seq: int,
         pool_dtype = jnp.float32 if isinstance(dtype, str) else dtype
     else:
         pool_dtype = jnp.int8
-    pool = (layout.num_pages, tok, KV, D)
+    pool = page_layout.pool_shape(layout.num_pages, tok, KV, D, pool_dtype)
     for g in group_plan(spec):
         if g.kind == "conv":
             cache["groups"].append([{} for _ in range(g.n)])
@@ -619,7 +636,8 @@ def _scatter_kv_rows(kv: Dict, name: str, rows: jnp.ndarray,
     positions (``tgt_page``, ``tgt_off``) (N,), quantizing per the
     pool's layout.  Returns the updated pool entries ({name}_pages and,
     when quantized, {name}_scale — lane-major (P, KV, page), so a
-    token's scales land at [page, :, off]).
+    token's scales land at [page, :, off]).  Float pools take the rows
+    in either layout (``kernels.page_layout.write_tokens``).
 
     int4 pools nibble-pack two adjacent tokens per byte, so a token
     write is a read-modify-write of its byte that must preserve the
@@ -632,8 +650,8 @@ def _scatter_kv_rows(kv: Dict, name: str, rows: jnp.ndarray,
     pool = kv[name + "_pages"]
     quant = _paged_quant(kv)
     if quant == "none":
-        return {name + "_pages": pool.at[tgt_page, tgt_off].set(
-            rows.astype(pool.dtype))}
+        return {name + "_pages": page_layout.write_tokens(
+            pool, rows, tgt_page, tgt_off)}
     if quant == "int8":
         qrow, srow = quantize_kv_int8(rows)
         return {name + "_pages": pool.at[tgt_page, tgt_off].set(qrow),
@@ -685,7 +703,7 @@ def _attn_decode_paged(spec, p, x, pos, kv, block_tables, *,
     from repro.kernels import ops as kops
     B = x.shape[0]
     H, KV, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
-    page = kv["k_scale"].shape[-1] if "k_scale" in kv else kv["k_pages"].shape[1]
+    page = _entry_page(kv, D)
     q = qdot(x, p["wq"]).reshape(B, 1, H, D)
     k = qdot(x, p["wk"]).reshape(B, 1, KV, D)
     v = qdot(x, p["wv"]).reshape(B, 1, KV, D)
@@ -748,7 +766,7 @@ def _attn_decode_window_paged(spec, p, x, pos, lens, kv, block_tables, *,
     from repro.kernels import ops as kops
     B, K = x.shape[:2]
     H, KV, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
-    page = kv["k_scale"].shape[-1] if "k_scale" in kv else kv["k_pages"].shape[1]
+    page = _entry_page(kv, D)
     q = qdot(x, p["wq"]).reshape(B, K, H, D)
     k = qdot(x, p["wk"]).reshape(B, K, KV, D)
     v = qdot(x, p["wv"]).reshape(B, K, KV, D)
@@ -821,7 +839,7 @@ def _suffix_attn_paged(spec, p, xn, positions, kv, pref_pages, prefix_len,
     B, S = xn.shape[:2]
     H, KV, D = spec.num_heads, spec.num_kv_heads, spec.head_dim
     quant = _paged_quant(kv)
-    page = kv["k_scale"].shape[-1] if quant != "none" else kv["k_pages"].shape[1]
+    page = _entry_page(kv, D)
     npr = pref_pages.shape[0] * page
     q = qdot(xn, p["wq"]).reshape(B, S, H, D)
     k = qdot(xn, p["wk"]).reshape(B, S, KV, D)
@@ -830,8 +848,8 @@ def _suffix_attn_paged(spec, p, xn, positions, kv, pref_pages, prefix_len,
     q = L.rope(q, positions, spec.rope_theta)
     k = L.rope(k, positions, spec.rope_theta)
 
-    kp = kv["k_pages"][pref_pages]                       # (n, page, KV, D)
-    vp = kv["v_pages"][pref_pages]
+    kp = page_layout.pages_from_pool(kv["k_pages"][pref_pages], D)
+    vp = page_layout.pages_from_pool(kv["v_pages"][pref_pages], D)
     if quant == "int4":
         kp = unpack_int4(kp, axis=1)
         vp = unpack_int4(vp, axis=1)
@@ -917,7 +935,7 @@ def prefill_paged(params, spec: ModelSpec, tokens, cache, slot, bt_row,
     Routed-expert layers route padding rows nowhere and append their
     rows per held expert to ``expert_rows`` when given.
     """
-    page = paged_page_size(cache)
+    page = paged_page_size(cache, spec.head_dim)
     S = tokens.shape[1]
     positions = prefix_len + jnp.arange(S)[None]         # (1, S) absolute
     pref_pages = bt_row[:n_prefix_pages]

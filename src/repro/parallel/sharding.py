@@ -17,6 +17,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.model_config import ModelSpec
+from repro.kernels import page_layout
 
 
 def _axis_size(mesh: Mesh, name: str) -> int:
@@ -239,7 +240,8 @@ class ShardingRules:
 
     def cache_entry_pspec(self, name: str, shape: Tuple[int, ...]) -> P:
         """shape: per-layer cache entry, e.g. (B, S, KV, D) — or a PAGED
-        pool: ``k_pages``/``v_pages`` (P, tok, KV, D) and lane-major
+        pool: ``k_pages``/``v_pages`` (P, tok, KV, D) or lane-dense
+        (P, KV, tok·D/128, 128) (``kernels.page_layout``) and lane-major
         ``k_scale``/``v_scale`` (P, KV, page) shard their KV-HEAD dim
         over "model" (pages are the serve path's capacity unit, so the
         pool partitions by head, never by page — block tables stay
@@ -250,11 +252,13 @@ class ShardingRules:
         dp = dp_axes(self.mesh)
         dpa = dp if len(dp) > 1 else dp[0]
         if name in ("k_pages", "v_pages"):
-            KV = shape[2]
+            axis = page_layout.kv_axis(shape, sp.head_dim)
+            KV = shape[axis]
             if tp <= 1:
                 return P(None, None, None, None)
             if _div(KV, tp) and _div(sp.num_heads, tp):
-                return P(None, None, "model", None)
+                return P(*["model" if i == axis else None
+                           for i in range(len(shape))])
             return self._paged_pool_fallback(name, shape, KV)
         if name in ("k_scale", "v_scale") and len(shape) == 3:
             KV = shape[1]

@@ -92,7 +92,7 @@ def _admit_fn(params, batch, cache, slot, true_len, bt_row, *, spec, impl,
     logits, pre = lm.prefill(params, spec, batch,
                              max_seq=batch["tokens"].shape[1],
                              impl=impl, true_len=true_len, expert_rows=rows)
-    page = lm.paged_page_size(cache)
+    page = lm.paged_page_size(cache, spec.head_dim)
     n = batch["tokens"].shape[1] // page          # prompt pages (static)
     if ring:
         R = bt_row.shape[0]
@@ -276,8 +276,12 @@ class PagedKVBackend:
         """Record this backend's device calls as spans of ``recorder``
         (the owning engine's ``spans.SpanRecorder``), and a routed-expert
         spec's ``EXPERT_COUNTERS`` in the recorder's ``stats``; until an
-        engine binds one, they record nothing."""
+        engine binds one, they record nothing.  ``kv_lane_dense_layers``
+        in ``stats`` counts the attention layers whose pools this
+        backend's cache stores lane-dense (``kernels.page_layout``)."""
         self._spans = recorder
+        recorder.stats["kv_lane_dense_layers"] = lm.lane_dense_layers(
+            self.cache, self.spec.head_dim)
         if self.spec.moe is not None:
             for name in EXPERT_COUNTERS:
                 recorder.stats.setdefault(name, 0)

@@ -56,6 +56,7 @@ from repro.core.analytical import (MemoryBreakdown, PagedCachePlan,
                                    kv_budget, kv_cache_dtype_bytes,
                                    page_bytes, plan_paged_cache)
 from repro.core.model_config import ModelSpec
+from repro.kernels import page_layout
 from repro.models import lm
 from repro.quant.quantize import (lane_major_scales, pack_int4,
                                   quantize_kv_int4, quantize_kv_int8)
@@ -549,8 +550,9 @@ def scatter_prompt_pages(cache_groups, prefill_groups, pv: jnp.ndarray,
     scheduler's fused jitted admission go through it.  Quantized pools
     quantize rows and fill the scale pools alongside; int4 additionally
     nibble-packs token pairs (whole pages are written, so no
-    read-modify-write is needed here).  Layers without pages (conv)
-    pass through."""
+    read-modify-write is needed here); float pools take the pages in
+    their layout (``kernels.page_layout.pages_to_pool``).  Layers
+    without pages (conv) pass through."""
     n = pv.shape[0]
     new_groups = []
     for cg, pg in zip(cache_groups, prefill_groups):
@@ -578,7 +580,7 @@ def scatter_prompt_pages(cache_groups, prefill_groups, pv: jnp.ndarray,
                         pv].set(lane_major_scales(srows))
                 else:
                     new_entry[name + "_pages"] = pool.at[pv].set(
-                        rows.astype(pool.dtype))
+                        page_layout.pages_to_pool(rows, pool))
             new_layers.append(new_entry)
         new_groups.append(new_layers)
     return new_groups
@@ -611,7 +613,7 @@ def write_prompt(cache, spec: ModelSpec, slot: int, pages: Sequence[int],
     """Scatter a contiguous prefill cache (one sequence, max_seq padded
     to a page multiple) into ``pages`` and point ``slot``'s block table
     at them.  Returns the updated paged-cache pytree (functional)."""
-    page = lm.paged_page_size(cache)
+    page = lm.paged_page_size(cache, spec.head_dim)
     pv = jnp.asarray(list(pages), jnp.int32)
     new_groups = scatter_prompt_pages(cache["groups"],
                                       prefill_cache["groups"], pv, page)

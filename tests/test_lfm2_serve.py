@@ -227,6 +227,51 @@ def test_expert_shares_sum_to_the_uncut_layer(tiny):
     assert sum(int(r.sum()) for r in rows) == 24 * dm.top_k
 
 
+def test_lane_dense_pools_keep_tokens_through_swap_and_chunking(tiny):
+    """The tiny spec's heads are 64 wide, so its fp32 pools are stored
+    lane-dense (``kernels/page_layout.py``).  A slot's greedy tokens are
+    the same uninterrupted, after its pages went to the host and came
+    back into other pages (the old ones zeroed), and after a chunked
+    prefill; the backend counts every attention layer as lane-dense,
+    and none for int8 pools."""
+    _, spec, _, params = tiny
+    prompt = _tokens(41, seed=8)
+    pages = list(range(33, 33 + pc.pages_needed(len(prompt) + 64, PAGE)))
+    fresh = [p + 20 for p in pages]
+
+    def greedy(chunk=0, swap_at=None):
+        backend = SingleDeviceBackend(params, spec, _sched())
+        stats = {}
+        backend.bind_spans(SpanRecorder(stats))
+        out = [_admit(backend, prompt, slot=1, chunk=chunk)]
+        active = np.zeros(4, np.int32)
+        active[1] = 1
+        for i in range(10):
+            if i == swap_at:
+                blob = backend.swap_out(pages)
+                backend.swap_in(blob, fresh)
+                backend.swap_in(jax.tree_util.tree_map(np.zeros_like, blob),
+                                pages)
+                backend.write_block_entries(
+                    [(1, j, p) for j, p in enumerate(fresh)])
+            toks = np.zeros((4, 1), np.int32)
+            toks[1, 0] = out[-1]
+            nxt, _, ok = backend.decode(toks, active)
+            assert ok[1]
+            out.append(int(nxt[1, 0]))
+        return out, stats
+
+    want, stats = greedy()
+    n_attn = sum(k.startswith("attn") for k in spec.layer_kinds())
+    assert stats["kv_lane_dense_layers"] == n_attn == 1
+    assert greedy(swap_at=4)[0] == want
+    assert greedy(chunk=16)[0] == want
+    int8 = SingleDeviceBackend(params, spec, _sched(cache_dtype="int8"))
+    stats = {}
+    int8.bind_spans(SpanRecorder(stats))
+    assert stats["kv_lane_dense_layers"] == 0
+
+
 def _serve(spec, params, requests, **kw):
     eng = ContinuousBatchingEngine(params, spec, _sched(**kw))
     done = eng.run([Request(r.uid, r.prompt, r.max_new_tokens)

@@ -261,7 +261,7 @@ def test_init_paged_cache_int4_layout():
     assert entry["k_pages"].dtype == jnp.int8
     # lane-major scales: token dim last (one (8, 128) f32 tile per page)
     assert entry["k_scale"].shape == (8, spec.num_kv_heads, 16)
-    assert lm.paged_page_size(cache) == 16
+    assert lm.paged_page_size(cache, spec.head_dim) == 16
     assert lm._paged_quant(entry) == "int4"
     with pytest.raises(ValueError):
         lm.init_paged_cache(spec, 1, 48,

@@ -119,6 +119,32 @@ print('OK')
     assert "OK" in out
 
 
+def test_sharded_backend_lane_dense_pools():
+    """Heads of 64 (4 kv heads, 16-token pages) store their fp32 pools
+    lane-dense (``kernels/page_layout.py``): tp=2 and tp=4 shard them on
+    their kv-head axis, one kv head a device at tp=4, and stay within the
+    tolerance band of the single-device outputs."""
+    out = _run(PRELUDE + """
+from repro.kernels import page_layout
+wide = ASSIGNED['granite-3-8b'].scaled_down(layers=2, width=256, vocab=128)
+wide_params = lm.init(jax.random.PRNGKey(1), wide)
+base, base_eng = run_engine(1, 'fp32', spec=wide, params=wide_params)
+assert base_eng.stats['kv_lane_dense_layers'] == wide.num_layers
+for tp in (2, 4):
+    done, eng = run_engine(tp, 'fp32', spec=wide, params=wide_params)
+    assert eng.backend.pools_sharded, 'pools failed to shard'
+    entry = eng.backend.cache['groups'][0][0]
+    shape = entry['k_pages'].shape
+    assert page_layout.is_lane_dense(shape, wide.head_dim), shape
+    assert entry['k_pages'].sharding.spec[1] == 'model', shape
+    assert eng.stats['kv_lane_dense_layers'] == wide.num_layers
+    for a, b in zip(base, done):
+        assert_close_tokens(a.tokens, b.tokens, context=f'tp={tp} {a.uid}')
+print('OK')
+""")
+    assert "OK" in out
+
+
 def test_sharded_backend_preemption_parity_int4():
     """A pool too small for all admitted contexts forces preemption on
     both backends; the weight-sharded int4 engine stays within the

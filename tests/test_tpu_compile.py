@@ -8,15 +8,22 @@ decode widths: 8 slots, 32 query heads over 4 KV heads of 64, 16-token
 pages, for fp32, int8 and int4 pages and a 4-token verify window; and at
 the chip benchmark's widths (32 slots of 256 pages over an 8,193-page
 pool): DeepSeek-R1-Distill-Qwen-1.5B with fp32 pages and Granite-3.0-8B
-with int4 pages, whose streamed blocks have to fit the scoped VMEM.
+with int4 pages, whose streamed blocks have to fit the scoped VMEM.  At
+LFM2-8B-A1B's widths (heads of 64) one attention layer's decode, the
+token scatter and the kernel over lane-dense pools, has to compile with
+no op that copies, transposes or pads a whole pool; and the kernel
+compiles per device of a TinyLlama pool split over four chips.
 
 The topology is described inside a fixture of this file only: loading
 the TPU library at import time, or from a second file, would make the
 xdist workers collect different tests.
 """
 import functools
+import math
 import os
+import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -102,3 +109,100 @@ def test_routed_experts_compile_at_lfm2_widths(one_chip):
     compiled = jax.jit(functools.partial(L.routed_experts, spec)).lower(
         p, sds((32, 1, d))).compile()
     assert "ragged-dot" in compiled.as_text()
+
+
+#: Ops that move a whole array when applied to one.
+MOVES = {"copy", "copy-start", "transpose", "pad"}
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
+_OP = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                 r"([\w\-]+)\(")
+
+
+def _whole_pool_moves(hlo: str, pool_elems: int) -> list:
+    """Names of the ops of optimised ``hlo`` that copy, transpose or pad
+    (themselves or inside the fusion they are) an array of at least
+    ``pool_elems`` elements."""
+    fused, ops, comp = {}, [], None
+    for line in hlo.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            comp = head.group(1)
+            continue
+        op = _OP.match(line)
+        if op:
+            name, dims, opcode = op.groups()
+            fused.setdefault(comp, set()).add(opcode)
+            elems = math.prod(int(x) for x in dims.split(",") if x)
+            called = re.search(r"calls=%([\w.\-]+)", line)
+            ops.append((name, elems, opcode,
+                        called.group(1) if called else None))
+    return [name for name, elems, opcode, called in ops
+            if elems >= pool_elems and (opcode in MOVES or (
+                opcode == "fusion" and fused.get(called, set()) & MOVES))]
+
+
+def test_lfm2_attention_decode_moves_no_whole_pool(one_chip, monkeypatch):
+    """One LFM2-8B-A1B attention layer's decode at the benchmark's
+    widths (32 slots of 256 pages over 8,193-page fp32 pools, 32/8
+    heads of 64): the new token's K/V scatter into the pools in place
+    and the paged kernel reads them as they lie.  Pools stored
+    (P, 16, 8, 64) instead are converted and zero-padded whole on every
+    call."""
+    from repro.configs import ARCHS
+    from repro.kernels import ops
+    from repro.models import lm
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    spec = ARCHS["lfm2-8b-a1b"]
+    B, pps = 32, 256
+    layout = lm.PagedLayout(num_pages=B * pps + 1, page_size=16,
+                            pages_per_slot=pps)
+    cache = jax.eval_shape(functools.partial(
+        lm.init_paged_cache, spec, B, 4096, layout, jnp.float32))
+    kv = next(e for g in cache["groups"] for e in g if "k_pages" in e)
+    d, H, KV, D = (spec.d_model, spec.num_heads, spec.num_kv_heads,
+                   spec.head_dim)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    p = {"wq": sds((d, H * D), jnp.bfloat16),
+         "wk": sds((d, KV * D), jnp.bfloat16),
+         "wv": sds((d, KV * D), jnp.bfloat16),
+         "wo": sds((H * D, d), jnp.bfloat16),
+         "q_norm": sds((D,)), "k_norm": sds((D,))}
+    kv = {name: sds(a.shape, a.dtype) for name, a in kv.items()}
+
+    def layer(p, x, pos, kv, tables):
+        return lm._attn_decode_paged(spec, p, x, pos, kv, tables,
+                                     kind="attn")
+    compiled = jax.jit(layer, donate_argnums=(3,)).lower(
+        p, sds((B, 1, d), jnp.bfloat16), sds((B,), jnp.int32), kv,
+        sds((B, pps), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert _whole_pool_moves(hlo, math.prod(kv["k_pages"].shape)) == []
+
+
+def test_sharded_kernel_compiles_for_tinyllama_tp4(topo, monkeypatch):
+    """TinyLlama-1.1B's pools (4 kv heads of 64) split over the four
+    chips of a v5e 2x2, one kv head each: every device's share of a page
+    is still whole lane tiles, and the kernel compiles per device."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.kernels import ops, page_layout
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
+    shape = page_layout.pool_shape(POOL, PAGE, KV, D, jnp.float32)
+    axis = page_layout.kv_axis(shape, D)
+    assert shape[-1] == 128 and shape[axis] == 4
+
+    def sds(shp, spec, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shp, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+    pool = sds(shape, P(*["model" if i == axis else None
+                          for i in range(4)]))
+    f = functools.partial(ops.paged_attention_sharded, mesh,
+                          gather_output=False)
+    compiled = jax.jit(f).lower(
+        sds((B, H, D), P(None, "model", None)), pool, pool,
+        sds((B, PAGES_PER_SLOT), P(None, None), jnp.int32),
+        sds((B,), P(None), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
