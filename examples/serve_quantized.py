@@ -34,5 +34,4 @@ for precision in ("fp32", "int8", "int4"):
 
 print("\nINT8 halves and INT4 quarters the weight bytes — on the "
       "memory-bandwidth-bound decode path this is the paper's 2-3x speedup "
-      "(see benchmarks/table2_quant.py and the decode-cell hillclimb in "
-      "EXPERIMENTS.md §Perf).")
+      "(python -m repro.launch.profile --sweep).")

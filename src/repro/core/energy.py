@@ -7,8 +7,7 @@ E = FLOPs x e_flop + M x e_byte   (joules per step / per token)
 is the serving form the continuous-batching predictor uses (one
 scheduler iteration's dynamic energy plus the board's static draw over
 the iteration, divided by the tokens the iteration commits) — the
-number ``benchmarks/serve_throughput.py`` prints next to the measured
-run and the paper's 35-50% INT4 reduction band is asserted against
+number the paper's 35-50% INT4 reduction band is asserted against
 (tests/test_analytical.py).  The static term matters: dynamic INT4
 energy drops near the byte ratio (~8x), but the board burns
 ``p_static`` watts for the whole step either way, which is exactly why

@@ -246,8 +246,7 @@ def predict_serve_throughput(spec: ModelSpec, hw: HardwareSpec,
     sets how many slots the page pool actually sustains
     (``analytical.effective_slots``) — lazy allocation holds only the
     pages written so far, so the same pool carries more concurrent
-    requests.  Returns tokens/sec for both plus the ratio — the
-    analytical counterpart of ``benchmarks/serve_throughput.py``.
+    requests.  Returns tokens/sec for both plus the ratio.
 
     ``spec_k``/``acceptance_rate`` model self-speculative decoding on
     the continuous engine (the static baseline stays sequential): each
@@ -271,9 +270,7 @@ def predict_serve_throughput(spec: ModelSpec, hw: HardwareSpec,
     (each device's KV-head slice of the whole pool) and
     ``per_device_pool_occupancy`` (identical on every device: a page's
     rows span all shards, so occupancy is a property of the block
-    tables, which are replicated host state) — the numbers
-    ``benchmarks/serve_throughput.py --devices N`` prints measured
-    occupancy against.
+    tables, which are replicated host state).
 
     ``dp`` is the data-parallel replica count (``serve/router.py``):
     replicas are fully independent engines, so aggregate throughput is
@@ -288,8 +285,7 @@ def predict_serve_throughput(spec: ModelSpec, hw: HardwareSpec,
     is byte-identical to the pre-cluster model.
 
     ``chunk_tokens`` models the scheduler's chunked-prefill budget and
-    turns on the latency DECOMPOSITION the open-loop benchmark
-    (``serve_throughput.py --open-loop``) plots predictions against.
+    turns on the latency DECOMPOSITION.
     Every call returns ``predicted_itl_s`` (steady-state inter-token
     latency: one mixed iteration per committed window token),
     ``predicted_itl_worst_s`` (the iteration a co-scheduled admission
@@ -310,8 +306,7 @@ def predict_serve_throughput(spec: ModelSpec, hw: HardwareSpec,
     plus one admission iteration instead of re-prefilling the whole
     context — the result gains the ``swap_vs_recompute`` keys plus
     ``predicted_resume_ttft_s`` / ``predicted_recompute_ttft_s`` and
-    ``swap_cheaper`` (1.0/0.0), the numbers the ``--swap`` multi-turn
-    benchmark gate prints its measured TTFTs against.
+    ``swap_cheaper`` (1.0/0.0).
 
     ``window`` > 0 models the ring-paged sliding-window engine
     (``SchedulerConfig.windowed_kv``) against the SAME full-attention
@@ -319,8 +314,7 @@ def predict_serve_throughput(spec: ModelSpec, hw: HardwareSpec,
     bound — so ``effective_slots`` (and with it admitted concurrency at
     fixed pool bytes) multiplies — and each decode step streams at most
     ``window`` tokens of KV.  The result echoes ``window`` and
-    ``ring_pages_per_slot``; the ``--window`` benchmark gate measures
-    its concurrency ratio against this cell.
+    ``ring_pages_per_slot``.
     """
     avg_ctx = avg_prompt + avg_new / 2
     live = effective_slots(plan, slots, avg_prompt, avg_new, admission,
@@ -560,7 +554,7 @@ def serve_availability(spec: ModelSpec, hw: HardwareSpec,
                        offered_tokens_per_s: float | None = None,
                        **predict_kw) -> Dict[str, float]:
     """Fleet capacity and goodput with ``failed`` of ``dp`` replicas
-    dead — the analytical counterpart of the ``--chaos`` benchmark gate.
+    dead.
 
     Replicas are independent engines behind the router, so degraded
     capacity is simply the survivors' aggregate rate; what failure

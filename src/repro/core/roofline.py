@@ -104,22 +104,3 @@ class CellResult:
     def load(path: str | Path) -> "CellResult":
         return CellResult(**json.loads(Path(path).read_text()))
 
-
-def load_all(directory: str | Path):
-    d = Path(directory)
-    if not d.exists():
-        return []
-    return [CellResult.load(f) for f in sorted(d.glob("*.json"))]
-
-
-def markdown_table(cells, keys=("arch", "shape", "mesh", "hlo_gflops", "hlo_gb",
-                                "coll_mb", "t_compute_ms", "t_memory_ms",
-                                "t_collective_ms", "dominant", "useful_ratio",
-                                "roofline_frac")) -> str:
-    lines = ["| " + " | ".join(keys) + " |",
-             "|" + "|".join("---" for _ in keys) + "|"]
-    for c in cells:
-        row = c.row()
-        fmt = lambda v: f"{v:.3g}" if isinstance(v, float) else str(v)
-        lines.append("| " + " | ".join(fmt(row[k]) for k in keys) + " |")
-    return "\n".join(lines)

@@ -4,7 +4,7 @@ JAX's persistent cache only hits when the directory does not move
 between runs.  ``JAX_COMPILATION_CACHE_DIR``, when set, is left to JAX
 (it reads the variable itself); otherwise the cache lives in
 ``<checkout>/.jax_cache``.  Entry points (``chip_smoke.py``,
-``launch/serve.py``, ``benchmarks/serve_throughput.py``) call
+``launch/serve.py``, ``chipbench/run.py``) call
 ``enable_compile_cache()`` before their first compile; importing a
 library module or running the tests never turns the cache on.
 """

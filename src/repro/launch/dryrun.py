@@ -18,8 +18,7 @@ Per cell this driver:
      .compile()s it,
   4. prints memory_analysis() + cost_analysis(), parses collective bytes
      from the post-SPMD HLO, and
-  5. writes a CellResult JSON consumed by benchmarks/roofline_report.py
-     and EXPERIMENTS.md.
+  5. writes a CellResult JSON.
 
 Usage:
   python -m repro.launch.dryrun --arch glm4-9b --shape train_4k --mesh single

@@ -652,8 +652,8 @@ class ShardedPagedBackend(SingleDeviceBackend):
 def make_backend(params: Any, spec: ModelSpec, cfg, *,
                  devices: int = 1,
                  device_list: Optional[List] = None) -> PagedKVBackend:
-    """Backend factory the launcher/benchmarks use: ``devices`` == 1 is
-    the single-device pool, > 1 the tensor-parallel backend (KV pools
+    """Backend factory the launcher and the benchmark use: ``devices``
+    == 1 is the single-device pool, > 1 the tensor-parallel backend (KV pools
     AND weights sharded) over the first ``devices`` jax devices —
     or over an explicit ``device_list`` (the dp router hands each
     replica its own disjoint slice; a one-device slice commits the

@@ -28,9 +28,7 @@ Two serving modes live in this package:
   slot (greedy recompute, prefix pages retained by refcount).  Each
   iteration decodes one token for all live slots through the
   gather-based paged attention op (``kernels/paged_attention.py``).
-  ``benchmarks/serve_throughput.py`` measures the tokens/sec win over
-  ``generate()`` and (``--prefix``) the prefill-token reduction on
-  templated workloads.  The scheduler drives the device through a
+  The scheduler drives the device through a
   ``serve.backend.PagedKVBackend``: ``--devices N`` serves the same
   host logic tensor-parallel (page pools sharded over the KV-head dim,
   block tables replicated, paged attention per shard) with
@@ -89,22 +87,6 @@ def generate(params: Any, spec: ModelSpec, batch: Dict[str, jnp.ndarray],
     (cache, _), toks = jax.lax.scan(step, (cache, tok0), keys)
     out = jnp.concatenate([tok0[:, None], toks.T], axis=1)[:, :num_steps + 1]
     return {"tokens": out, "cache_pos": cache["pos"]}
-
-
-_GEN_JIT_CACHE: Dict[Any, Any] = {}
-
-
-def jitted_generate(spec: ModelSpec, cfg: ServeConfig):
-    """jit-compiled ``generate`` closure, cached per (spec, cfg) so repeated
-    workloads (benchmark passes, serving loops) share compiles.  Returns
-    ``fn(params, batch, num_steps)`` with ``num_steps`` static."""
-    key = (spec, cfg.max_seq, cfg.temperature, cfg.weight_precision,
-           str(cfg.cache_dtype), cfg.attention_impl)
-    if key not in _GEN_JIT_CACHE:
-        def fn(params, batch, num_steps):
-            return generate(params, spec, batch, num_steps, cfg)
-        _GEN_JIT_CACHE[key] = jax.jit(fn, static_argnums=(2,))
-    return _GEN_JIT_CACHE[key]
 
 
 def make_serve_step(spec: ModelSpec):

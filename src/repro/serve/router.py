@@ -45,9 +45,9 @@ while it has work — is EVICTED via ``fail()``, which migrates BOTH its
 queued requests and its admitted slots (``engine.export_active`` turns
 partial outputs into resume records; rendezvous remaps only the dead
 replica's keys) to survivors.  Zero requests are lost even on a crash
-mid-decode: the failover contract the ``--chaos`` benchmark gate and
-tests/test_serve_faults.py pin.  ``add()`` rejoins a recovered
-replica (rendezvous shifts back exactly the keys it wins).
+mid-decode: the failover contract tests/test_serve_faults.py pins.
+``add()`` rejoins a recovered replica (rendezvous shifts back exactly
+the keys it wins).
 
 With an optional ``ServeSLO`` policy, ``submit`` applies BACKPRESSURE
 from the analytical model instead of queue cost alone: the policy
@@ -173,8 +173,7 @@ class PrefixRouter:
     list, ids becoming "r0".."rN-1").  Pass ``engines=None`` ids-only
     for pure routing-policy use (the determinism tests).  ``mode`` is
     "prefix" (rendezvous on the template prefix) or "random" (seeded
-    uniform — the affinity-free baseline the benchmark compares
-    against)."""
+    uniform — the affinity-free baseline the tests compare against)."""
 
     def __init__(self, engines=None, *, replica_ids: Optional[Sequence[str]] = None,
                  page_size: int = 16, route_pages: int = 2,

@@ -31,7 +31,7 @@ runs the vLLM-style alternative on top of the paged KV cache:
   prefilled slot carries across iterations with no new kernel; only
   the FINAL chunk's logits seed decoding, and chunking changes
   scheduling only — per-request outputs stay token-for-token the
-  unchunked engine's (the ``--open-loop`` benchmark gate);
+  unchunked engine's;
 * every iteration decodes one verify WINDOW — a single token unless
   speculating (``spec_k``) — for ALL live fully-prefilled slots in a
   single fixed-shape jitted step; when a slot crosses a page boundary
@@ -76,8 +76,7 @@ runs the vLLM-style alternative on top of the paged KV cache:
   is memory-bound on every edge roofline the paper profiles (weights +
   pages re-read per step), so each accepted token amortizes that
   traffic — emissions stay token-for-token identical to ``spec_k=1``
-  greedy decode (asserted in tests/test_spec_decode.py and the
-  ``benchmarks/serve_throughput.py --spec-decode`` gate).
+  greedy decode (asserted in tests/test_spec_decode.py).
 
 * a spec with gated short-convolution layers keeps a per-slot conv
   state beside the pages that only the slot's own prefill writes: a
@@ -129,6 +128,9 @@ from repro.serve import paged_cache as pc
 from repro.serve.backend import PagedKVBackend, SingleDeviceBackend
 from repro.serve.spans import SpanRecorder
 from repro.serve.spec_decode import NGramDraftTable
+
+# n-gram length the spec_k > 1 drafter matches a slot's context on
+SPEC_NGRAM = 2
 
 
 @dataclass
@@ -187,9 +189,8 @@ class SchedulerConfig:
     # self-speculative decoding: verify windows of up to spec_k tokens
     # per slot per iteration (1 = off, the plain one-token decode step);
     # drafts come from an n-gram prompt-lookup table over each request's
-    # own context (no draft model), matching on spec_ngram-grams
+    # own context (no draft model), matching on SPEC_NGRAM-grams
     spec_k: int = 1
-    spec_ngram: int = 2
     # chunked prefill: per-ITERATION prefill-token budget (0 = off, the
     # legacy admit-the-whole-prompt path).  Charged in bucket-padded
     # (power-of-two-page) widths — admission is cost-aware in TOKENS,
@@ -212,7 +213,7 @@ class SchedulerConfig:
     # auto-detects (ring when every KV layer is attn_local with a
     # window — gemma-style local stacks), False forces the mask-only
     # reference (windowed attention math, full-attention memory: the
-    # token-identity baseline the --window gate compares against), True
+    # token-identity baseline the ring is tested against), True
     # asserts the stack qualifies.  With the ring each slot's KV is
     # bounded at O(window) pages forever — out-of-window pages are
     # recycled in place when exclusively owned and their reference
@@ -836,7 +837,7 @@ class ContinuousBatchingEngine:
         slot.last_token = tok0
         slot.generated.append(tok0)
         if self.cfg.spec_k > 1:
-            draft = NGramDraftTable(self.cfg.spec_ngram)
+            draft = NGramDraftTable(SPEC_NGRAM)
             draft.extend(slot.prompt.tolist())
             draft.extend([tok0])
             slot.draft = draft

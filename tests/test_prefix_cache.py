@@ -478,7 +478,9 @@ def test_prefix_cache_on_off_token_identical(dtype):
         assert a.prompt_len == b.prompt_len
     assert eng_on.stats["prefix_hit_tokens"] > 0
     assert eng_on.stats["cow_copies"] >= 1          # extension request
-    assert eng_on.stats["prefill_tokens"] < eng_off.stats["prefill_tokens"]
+    # hits skip at least 30% of the prefill tokens
+    assert eng_on.stats["prefill_tokens"] <= \
+        0.7 * eng_off.stats["prefill_tokens"]
     # store retains pages by refcount until flushed; then fully clean
     eng_on.alloc.check()
     eng_on.prefix_cache.flush()

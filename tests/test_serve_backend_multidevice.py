@@ -12,32 +12,11 @@ not bitwise identity; only the odd-KV replicate fallback, which keeps
 weights replicated too, still promises exact tokens.
 
 jax locks the device count at first init, so these run in subprocesses
-with XLA_FLAGS=--xla_force_host_platform_device_count=8 (the same
-mechanism as tests/test_sharding_multidevice.py).
+over 8 forced host devices (``forced_devices.run_on_devices``).
 """
-import os
-import subprocess
-import sys
-import textwrap
-
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _run(code: str, timeout=900):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    # tests/ on the path so subprocess code can import the shared
-    # tolerance helpers
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(ROOT, "src"), os.path.join(ROOT, "tests")])
-    p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                       capture_output=True, text=True, timeout=timeout,
-                       env=env)
-    assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr}"
-    return p.stdout
-
+from forced_devices import run_on_devices
 
 PRELUDE = """
 import numpy as np, jax, jax.numpy as jnp
@@ -90,7 +69,7 @@ def test_sharded_backend_token_parity(cache_dtype):
     sharing + mid-page CoW + suffix prefill), for every cache dtype;
     pools AND weights really shard, per-device weight bytes drop below
     0.6x the replicated baseline, and every page reference unwinds."""
-    out = _run(PRELUDE + f"""
+    out = run_on_devices(PRELUDE + f"""
 base, base_eng = run_engine(1, {cache_dtype!r})
 assert base_eng.stats['prefix_hit_tokens'] > 0
 rep_bytes = base_eng.backend.param_bytes_per_device()
@@ -124,7 +103,7 @@ def test_sharded_backend_lane_dense_pools():
     lane-dense (``kernels/page_layout.py``): tp=2 and tp=4 shard them on
     their kv-head axis, one kv head a device at tp=4, and stay within the
     tolerance band of the single-device outputs."""
-    out = _run(PRELUDE + """
+    out = run_on_devices(PRELUDE + """
 from repro.kernels import page_layout
 wide = ASSIGNED['granite-3-8b'].scaled_down(layers=2, width=256, vocab=128)
 wide_params = lm.init(jax.random.PRNGKey(1), wide)
@@ -152,7 +131,7 @@ def test_sharded_backend_preemption_parity_int4():
     reference (the recompute-requeue path crosses admit/release/CoW on
     sharded pools).  Preemption COUNTS stay exactly equal: the
     allocator's page arithmetic depends on lengths, not token values."""
-    out = _run(PRELUDE + """
+    out = run_on_devices(PRELUDE + """
 rng = np.random.default_rng(2)
 T = rng.integers(0, 128, size=16).astype(np.int32)
 reqs = [Request(i, np.concatenate(
@@ -179,7 +158,7 @@ def test_odd_kv_heads_fall_back_to_replication():
     fallback keeps the exact bitwise token contract, so this stays
     ``np.array_equal``, not the tolerance band.  The warning fires once
     per (name, shape): a second engine over the same spec adds none."""
-    out = _run(PRELUDE + """
+    out = run_on_devices(PRELUDE + """
 import warnings
 spec1 = spec.with_(num_kv_heads=1)          # MQA: kv=1, tp=2 cannot divide
 params1 = lm.init(jax.random.PRNGKey(0), spec1)
@@ -216,8 +195,10 @@ def test_sharded_spec_decode_token_parity():
     band of the single-device NON-speculative greedy output, for every
     cache dtype — speculation and sharding compose, and acceptance is
     still self-consistent (every emitted token is the verify program's
-    own argmax)."""
-    out = _run(PRELUDE + """
+    own argmax), and its draft acceptance stays within the band of the
+    drafter replayed over the single-device greedy streams."""
+    out = run_on_devices(PRELUDE + """
+from tolerance import assert_acceptance_in_band
 # decode budgets long enough that greedy streams reach their
 # repetitive tails — otherwise the n-gram table never proposes and
 # nothing would actually be speculated
@@ -247,6 +228,7 @@ for cache_dtype in ('fp32', 'int8', 'int4'):
     for a, b in zip(base, done):
         assert_close_tokens(a.tokens, b.tokens,
                             context=f'{cache_dtype} uid={a.uid}')
+    assert_acceptance_in_band(eng.stats, reqs, base, 4, context=cache_dtype)
 print('OK')
 """)
     assert "OK" in out
@@ -256,7 +238,7 @@ def test_per_device_budget_scales_pool():
     """make_layout(tp=N): the same per-device byte budget addresses ~N x
     more pages (each device stores only its KV-head slice of a page),
     and plan_for_layout(tp=) prices the per-device share."""
-    out = _run("""
+    out = run_on_devices("""
 from repro.configs import ASSIGNED
 from repro.serve.paged_cache import make_layout, plan_for_layout
 spec = ASSIGNED['granite-3-8b'].scaled_down(layers=2, width=64, vocab=128)
@@ -283,7 +265,7 @@ def test_sharded_backend_swap_roundtrip_and_parity(cache_dtype):
     blob is layout-independent), and (b) an engine under pool pressure
     that swaps instead of preempting stays within the tolerance band
     of the single-device no-swap output, with the host pool drained."""
-    out = _run(PRELUDE + f"""
+    out = run_on_devices(PRELUDE + f"""
 cfg = SchedulerConfig(max_slots=3, page_size=16, max_seq=96, num_pages=24,
                       cache_dtype={cache_dtype!r})
 backend = make_backend(params, spec, cfg, devices=2)

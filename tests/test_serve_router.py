@@ -10,6 +10,7 @@ building a single engine.
 import numpy as np
 import pytest
 
+from forced_devices import run_on_devices
 from repro.serve.router import PrefixRouter, pick_replica, route_key
 
 
@@ -89,12 +90,64 @@ def test_pick_replica_rejects_empty():
 
 
 def test_random_mode_ignores_prefix():
-    """The benchmark's baseline: random mode spreads one template's
+    """The routing baseline: random mode spreads one template's
     requests across replicas (seeded, so the comparison reproduces)."""
     router = PrefixRouter(replica_ids=["r0", "r1"], mode="random", seed=0)
     prompts = _templated_prompts(n_templates=1, per_template=32)[0]
     picks = {router.route(p) for p in prompts}
     assert picks == {"r0", "r1"}
+
+
+def test_prefix_routing_beats_random_on_prefix_hits():
+    """dp=2 replicas of tp=2 (``make_replicas`` over 8 forced host
+    devices, int4 pages) on a 4-template workload: prefix routing's
+    aggregate prefix-cache hit tokens beat seeded-random routing's, and
+    every request's output stays within the tolerance band of one dp=1
+    replica's.  jax fixes the device count at start-up, so the fleets
+    run in a subprocess."""
+    out = run_on_devices("""
+        import jax, numpy as np
+        from tolerance import assert_close_tokens
+        from repro.configs import ASSIGNED
+        from repro.models import lm
+        from repro.serve.router import PrefixRouter, make_replicas
+        from repro.serve.scheduler import Request, SchedulerConfig
+
+        spec = ASSIGNED["granite-3-8b"].scaled_down(layers=2, width=64,
+                                                    vocab=256)
+        params = lm.init(jax.random.PRNGKey(0), spec)
+        rng = np.random.default_rng(0)
+        templates = [rng.integers(0, 256, size=64).astype(np.int32)
+                     for _ in range(4)]
+        reqs = [Request(i, np.concatenate([
+                    templates[i % 4],
+                    rng.integers(0, 256, size=int(rng.integers(8, 17)))
+                    .astype(np.int32)]), int(rng.integers(16, 25)))
+                for i in range(16)]
+        cfg = SchedulerConfig(max_slots=4, page_size=16, max_seq=160,
+                              kv_budget_bytes=64e6, cache_dtype="int4")
+
+        def fleet(dp, mode):
+            engines = make_replicas(params, spec, cfg, dp=dp, tp=2)
+            router = PrefixRouter(engines, mode=mode, seed=0,
+                                  page_size=cfg.page_size)
+            done = router.run([Request(r.uid, r.prompt.copy(),
+                                       r.max_new_tokens) for r in reqs])
+            for eng in engines:
+                eng.alloc.check()
+            assert len(done) == len(reqs)
+            return (sorted(done, key=lambda c: c.uid),
+                    router.aggregate_stats()["prefix_hit_tokens"])
+
+        base, _ = fleet(1, "prefix")
+        routed, hits_prefix = fleet(2, "prefix")
+        _, hits_random = fleet(2, "random")
+        assert hits_prefix > hits_random, (hits_prefix, hits_random)
+        for a, b in zip(base, routed):
+            assert_close_tokens(a.tokens, b.tokens, context=f"uid {a.uid}")
+        print("OK")
+    """)
+    assert "OK" in out
 
 
 # ---------------------------------------------------------------------------

@@ -316,16 +316,16 @@ class _RecordingBackend:
 @pytest.mark.parametrize("cache_dtype", ["fp32", "int4"])
 def test_chunked_prefill_outputs_identical(cache_dtype):
     """Chunked admission is a SCHEDULING change only: outputs must be
-    token-for-token the unchunked engine's, every iteration's padded
-    prefill tokens must fit the budget, and long prompts must actually
-    split (prefill_chunks > 0)."""
+    token-for-token the unchunked engine's over the same pool pages,
+    every iteration's padded prefill tokens must fit the budget, and
+    long prompts must actually split (prefill_chunks > 0)."""
     spec, params = _setup()
     rng = np.random.default_rng(5)
     shapes = [(40, 5), (9, 7), (33, 4), (21, 6), (56, 3), (14, 8)]
     reqs = [Request(i, rng.integers(0, 128, size=l).astype(np.int32), n)
             for i, (l, n) in enumerate(shapes)]
     budget = 16
-    outs = {}
+    outs, pool_pages = {}, {}
     for chunk in (0, budget):
         cfg = SchedulerConfig(max_slots=3, page_size=8, max_seq=80,
                               num_pages=40, cache_dtype=cache_dtype,
@@ -345,10 +345,13 @@ def test_chunked_prefill_outputs_identical(cache_dtype):
                 assert spent <= budget, rec.calls[before:]
         eng.alloc.check()
         outs[chunk] = sorted(done, key=lambda c: c.uid)
+        pool_pages[chunk] = eng.layout.num_pages
+        assert len(done) == len(reqs)
         if chunk:
             assert eng.stats["prefill_chunks"] > 0
             # both engines prefill every prompt token exactly once
             assert eng.stats["prefill_tokens"] == sum(l for l, _ in shapes)
+    assert pool_pages[0] == pool_pages[budget]
     for a, b in zip(outs[0], outs[budget]):
         assert a.uid == b.uid
         np.testing.assert_array_equal(a.tokens, b.tokens)
@@ -720,6 +723,92 @@ def test_session_stale_prompt_drops_and_admits_cold():
     eng.alloc.check()
 
 
+def _multi_turn_chat(eng, p0, extras, gaps, *, turns, max_new, stagger,
+                     sessions):
+    """Interleaved chat sessions on a virtual clock of ``eng.step()``
+    calls, skipped ahead while the engine holds no work.  Session s
+    submits turn 0 (``p0[s]``) at s * stagger, and turn t + 1 ``gaps[s,
+    t + 1]`` steps after turn t completes, extending its transcript by
+    ``extras[s, t + 1]``.  With ``sessions`` the turns carry the session
+    id (idle slots, parking, swap-in), else every turn re-submits the
+    full transcript.  Returns {(session, turn): output tokens}."""
+    ctx, live, out = {}, {}, {}
+    next_at = {s: s * stagger for s in p0}
+    turn = dict.fromkeys(p0, 0)
+    uid = clock = 0
+    while True:
+        for s, at in next_at.items():
+            if at is not None and at <= clock:
+                t = turn[s]
+                prompt = (p0[s] if t == 0
+                          else np.concatenate([ctx[s], extras[s, t]]))
+                eng.submit(Request(uid, prompt, max_new,
+                                   session=s if sessions else None))
+                live[uid] = (s, t, prompt)
+                next_at[s] = None
+                uid += 1
+        if eng.num_active == 0 and not eng.queue:
+            pending = [at for at in next_at.values() if at is not None]
+            if not pending:
+                return out
+            clock = max(clock + 1, min(pending))
+            continue
+        done = eng.step()
+        clock += 1
+        for c in done:
+            s, t, prompt = live.pop(c.uid)
+            assert c.status == "ok", (s, t, c.status)
+            out[s, t] = c.tokens
+            ctx[s] = np.concatenate([prompt, c.tokens])
+            turn[s] = t + 1
+            if t + 1 < turns:
+                next_at[s] = clock + gaps[s, t + 1]
+            elif sessions:
+                eng.end_session(s)
+
+
+def test_sessions_swap_tier_beats_recompute_multi_turn():
+    """Four interleaved 4-turn chat sessions with idle gaps, at equal
+    device pool pages: the session engine parks idle KV to the host
+    pool and swaps it back; the recompute engine re-prefills each
+    transcript.  Every turn's output is token-identical, the host tier
+    cycled, both engines drain, and the session engine's occupancy
+    (decode tokens per slot-iteration) is higher."""
+    spec, params = _setup()
+    rng = np.random.default_rng(1)
+    sessions, turns, slots = 4, 4, 3
+    p0 = {s: rng.integers(0, 128, size=24).astype(np.int32)
+          for s in range(sessions)}
+    extras = {(s, t): rng.integers(0, 128, size=16).astype(np.int32)
+              for s in range(sessions) for t in range(1, turns)}
+    gaps = {(s, t): int(rng.integers(6, 13))
+            for s in range(sessions) for t in range(1, turns)}
+    runs = {}
+    for swap in (True, False):
+        cfg = SchedulerConfig(max_slots=slots, page_size=8, max_seq=192,
+                              num_pages=44, prefill_chunk_tokens=16,
+                              host_pool_bytes=50e6 if swap else None,
+                              idle_park_iterations=4, debug_invariants=True)
+        eng = ContinuousBatchingEngine(params, spec, cfg)
+        out = _multi_turn_chat(eng, p0, extras, gaps, turns=turns,
+                               max_new=24, stagger=3, sessions=swap)
+        assert eng.num_idle == 0 and eng.num_parked == 0
+        eng.alloc.check()
+        runs[swap] = (eng, out)
+    (swap_eng, swap_out), (base_eng, base_out) = runs[True], runs[False]
+    assert sorted(swap_out) == sorted(base_out)
+    assert len(swap_out) == sessions * turns
+    for key, toks in base_out.items():
+        np.testing.assert_array_equal(swap_out[key], toks, err_msg=str(key))
+    assert swap_eng.layout.num_pages == base_eng.layout.num_pages
+    assert swap_eng.stats["swap_ins"] > 0
+
+    def occupancy(eng):
+        return eng.stats["decode_tokens"] / (eng.stats["iterations"] * slots)
+
+    assert occupancy(swap_eng) > occupancy(base_eng)
+
+
 # ---------------------------------------------------------------------------
 # Ring-paged sliding-window KV (windowed slots)
 # ---------------------------------------------------------------------------
@@ -735,43 +824,72 @@ def _windowed_setup(window=8, layers=2, width=64, vocab=128):
     return spec, params
 
 
-@pytest.mark.parametrize("spec_k", [1, 3])
-def test_ring_engine_token_identical_to_mask_only(spec_k):
+# (slots, num_pages, requests, prompt lengths, decode budgets, least
+# ratio of the ring's steady-state concurrency to the mask-only's)
+_RING_POOLS = {
+    # the ring's layout caps its pool at slots x ring pages: no claim
+    "roomy": (3, 40, 6, (5, 14), (18, 30), None),
+    # 12 slots over 30 usable pages: a ring slot holds 3 pages where a
+    # full-attention stream grows to 12, so the ring admits >= 2x
+    "pool-bound": (12, 31, 12, (5, 9), (36, 41), 2.0),
+}
+
+
+@pytest.mark.parametrize("spec_k,pool", [
+    pytest.param(1, "roomy", id="1"),
+    pytest.param(3, "roomy", id="3"),
+    pytest.param(1, "pool-bound", id="pool-bound"),
+])
+def test_ring_engine_token_identical_to_mask_only(spec_k, pool):
     """Ring eviction (windowed_kv auto-detected) vs the mask-only
     reference (windowed attention math, full-attention memory) on
     streams running many laps past the window: token-for-token
     identical, per-slot pages bounded at ring_pages (debug_invariants
     asserts it every step), and the ring actually recycled.  spec_k=3
     runs the same comparison under self-speculative decoding, whose
-    rollbacks repeatedly land verify windows across the ring wrap."""
+    rollbacks repeatedly land verify windows across the ring wrap.
+    Where the pool binds, the ring's steady-state concurrency (mean
+    live slots over the steps that began with a queue) is at least
+    twice the reference's at equal pool pages."""
+    slots, num_pages, n, plen, new, min_ratio = _RING_POOLS[pool]
     spec, params = _windowed_setup(window=8)
     rng = np.random.default_rng(4)
     reqs = [Request(i, rng.integers(1, 128,
-                                    size=int(rng.integers(5, 14))).astype(
-                        np.int32), int(rng.integers(18, 30)))
-            for i in range(6)]
+                                    size=int(rng.integers(*plen))).astype(
+                        np.int32), int(rng.integers(*new)))
+            for i in range(n)]
 
     def go(windowed_kv):
-        cfg = SchedulerConfig(max_slots=3, page_size=4, max_seq=48,
-                              num_pages=40, spec_k=spec_k,
+        cfg = SchedulerConfig(max_slots=slots, page_size=4, max_seq=48,
+                              num_pages=num_pages, spec_k=spec_k,
                               windowed_kv=windowed_kv,
                               debug_invariants=True)
         eng = ContinuousBatchingEngine(params, spec, cfg)
-        done = eng.run([Request(r.uid, r.prompt.copy(), r.max_new_tokens)
-                        for r in reqs])
+        for r in reqs:
+            eng.submit(Request(r.uid, r.prompt.copy(), r.max_new_tokens))
+        done, live = [], []
+        while eng.queue or eng.num_active:
+            backlog = bool(eng.queue)
+            done.extend(eng.step())
+            if backlog:
+                live.append(eng.num_active)
         eng.alloc.check()
-        return eng, sorted(done, key=lambda c: c.uid)
+        return eng, sorted(done, key=lambda c: c.uid), np.mean(live)
 
-    ring_eng, ring_done = go(None)
-    ref_eng, ref_done = go(False)
+    ring_eng, ring_done, ring_live = go(None)
+    ref_eng, ref_done, ref_live = go(False)
     assert ring_eng.ring and ring_eng.window == 8
     assert not ref_eng.ring and ref_eng.window == 0
     R = pc.ring_pages(8, 4, spec_k)
     assert ring_eng.layout.slots_pages(48) == R
     assert ring_eng.stats["ring_recycled_pages"] > 0
+    assert len(ring_done) == len(ref_done) == n
     for a, b in zip(ring_done, ref_done):
         assert a.uid == b.uid
         np.testing.assert_array_equal(a.tokens, b.tokens)
+    if min_ratio is not None:
+        assert ring_eng.layout.num_pages == ref_eng.layout.num_pages
+        assert ring_live >= min_ratio * ref_live, (ring_live, ref_live)
 
 
 def test_ring_engine_matches_static_generate_under_pressure():

@@ -1,30 +1,12 @@
 """Multi-device sharding tests.
 
 jax locks the device count at first init, so these run in subprocesses
-with XLA_FLAGS=--xla_force_host_platform_device_count=8 (the same
-mechanism the 512-device dry-run uses).
+over 8 forced host devices (``forced_devices.run_on_devices``; the
+512-device dry-run forces its devices the same way).
 """
-import json
-import os
-import subprocess
-import sys
-import textwrap
-
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _run(code: str, timeout=900):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    p = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                       capture_output=True, text=True, timeout=timeout,
-                       env=env)
-    assert p.returncode == 0, f"stdout:\n{p.stdout}\nstderr:\n{p.stderr}"
-    return p.stdout
-
+from forced_devices import run_on_devices
 
 PRELUDE = """
 import jax, jax.numpy as jnp
@@ -47,7 +29,7 @@ def sds(tree, sh):
 def test_sharded_train_step_runs_real_arrays():
     """Materialized sharded training step on a 4x2 mesh: loss finite and
     equal to the single-device value (SPMD correctness)."""
-    out = _run(PRELUDE + """
+    out = run_on_devices(PRELUDE + """
 import numpy as np
 from repro.data.synthetic import DataConfig, batch_at
 spec = ASSIGNED['granite-3-8b'].scaled_down(layers=2, width=64, vocab=64)
@@ -77,7 +59,7 @@ print('OK')
 def test_sharded_decode_and_long_context():
     """Decode with head-sharded KV cache and batch=1 seq-sharded cache
     (the long_500k layout) both run under SPMD."""
-    out = _run(PRELUDE + """
+    out = run_on_devices(PRELUDE + """
 from repro.core.model_config import ShapeSpec
 spec = ASSIGNED['qwen2-moe-a2.7b'].scaled_down(layers=2, width=64, vocab=64)
 rules = ShardingRules(mesh, spec)
@@ -107,7 +89,7 @@ print('OK')
 def test_dryrun_machinery_on_debug_mesh():
     """The exact dry-run pipeline (abstract params -> lower -> compile ->
     cost extraction) on an 8-device mesh for a reduced arch."""
-    out = _run(PRELUDE + """
+    out = run_on_devices(PRELUDE + """
 from repro.core import hlo_analysis
 spec = ASSIGNED['gemma3-4b'].scaled_down(layers=6, width=64, vocab=128)
 spec = spec.with_(sliding_window=16, local_global_ratio=5)
@@ -134,7 +116,7 @@ print('OK flops=%.3g coll=%.3g' % (cost['flops'], coll.total_bytes))
 def test_pod_axis_composes_with_data():
     """(pod, data, model) mesh: gradient sync spans pod x data (the
     multi-pod proof at debug scale)."""
-    out = _run("""
+    out = run_on_devices("""
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import AxisType

@@ -24,6 +24,8 @@ from repro.serve.scheduler import (ContinuousBatchingEngine, Request,
                                    SchedulerConfig)
 from repro.serve.spec_decode import NGramDraftTable
 
+from tolerance import assert_acceptance_in_band
+
 
 # ---------------------------------------------------------------------------
 # Draft table
@@ -244,7 +246,8 @@ def test_spec_engine_matches_greedy(cache_dtype):
     """spec_k=4 engine output == spec_k=1 engine output token-for-token
     for every cache dtype (prefix cache on: shared pages + CoW + suffix
     prefill all cross the window path), with every page reference
-    unwound."""
+    unwound, and the measured draft acceptance within the band of the
+    drafter replayed over the greedy streams."""
     spec, params = _setup()
     reqs = _reqs()
     base, _ = _run_engine(spec, params, reqs, 1, cache_dtype)
@@ -255,6 +258,7 @@ def test_spec_engine_matches_greedy(cache_dtype):
     # the iteration count below one-token-per-slot-per-step
     assert eng.stats["spec_steps"] > 0
     assert eng.stats["spec_accepted"] > 0
+    assert_acceptance_in_band(eng.stats, reqs, base, 4, context=cache_dtype)
     base_iters = _run_engine(spec, params, reqs, 1, cache_dtype)[1] \
         .stats["iterations"]
     assert eng.stats["iterations"] < base_iters

@@ -19,6 +19,8 @@ instead of per-test ad-hoc ``np.testing`` calls.
 import numpy as np
 
 from repro.serve.parity import token_match_fraction
+from repro.serve.scheduler import SPEC_NGRAM
+from repro.serve.spec_decode import NGramDraftTable
 
 
 def assert_close_tokens(a, b, *, min_match_frac: float = 0.9,
@@ -44,3 +46,33 @@ def assert_close_logits(a, b, *, rtol: float = 2e-5, atol: float = 1e-5,
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=rtol, atol=atol,
                                err_msg=f"logits differ [{context}]")
+
+
+def assert_acceptance_in_band(stats, reqs, greedy_done, spec_k: int, *,
+                              band: float = 0.15, context="") -> None:
+    """A speculative engine's measured draft acceptance
+    (``spec_accepted / spec_drafted``) lies within ``band`` of the
+    acceptance its n-gram drafter reaches when replayed over the
+    non-speculative greedy streams ``greedy_done``, window by window with
+    the scheduler's policy (a window drafts min(K, remaining) - 1 tokens,
+    none with one token of budget left).  The replay runs no model, so
+    only preemption and recompute can skew it."""
+    drafted = accepted = 0
+    for r, c in zip(reqs, greedy_done):
+        toks = [int(t) for t in c.tokens]
+        table = NGramDraftTable(SPEC_NGRAM)
+        table.extend(r.prompt.tolist() + toks[:1])
+        i = 1
+        while i < len(toks):
+            prop = table.propose(min(spec_k, len(toks) - i) - 1)
+            m = next((j for j, t in enumerate(prop) if t != toks[i + j]),
+                     len(prop))
+            drafted += len(prop)
+            accepted += m
+            table.extend(toks[i:i + m + 1])
+            i += m + 1
+    replayed = accepted / max(1, drafted)
+    measured = stats["spec_accepted"] / max(1, stats["spec_drafted"])
+    assert abs(measured - replayed) <= band, (
+        f"draft acceptance {measured:.3f} outside {replayed:.3f} +- {band}"
+        + (f" [{context}]" if context else ""))
